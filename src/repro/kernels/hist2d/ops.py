@@ -79,7 +79,8 @@ def batched_hist2d(bi, bj, weights, ki: int, kj: int, *,
         bi = jnp.pad(bi, pad)
         bj = jnp.pad(bj, pad)
         weights = jnp.pad(weights, pad)  # zero weight => no contribution
-    out = batched_hist2d_pallas(bi, bj, weights.astype(jnp.float32),
+    out = batched_hist2d_pallas(bi[:, None], bj[:, None],
+                                weights.astype(jnp.float32)[:, None],
                                 ki_pad, kj_pad, tn=tn,
                                 interpret=bool(interpret))
     return out[:, :ki, :kj].astype(weights.dtype)

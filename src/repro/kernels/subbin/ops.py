@@ -5,7 +5,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.subbin.ref import batched_subbin_hist_ref
-from repro.kernels.subbin.subbin import batched_subbin_hist_pallas
+from repro.kernels.subbin.subbin import (KQ_TILE,
+                                         batched_subbin_hist_pallas)
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -44,6 +45,8 @@ def batched_subbin_hist(cell, sub, weights, ncell: int, s_max: int, *,
     p, n = cell.shape
     k = ncell * s_max
     kq = _round_up(-(-k // 128), 8)       # ceil(k/128), sublane-aligned
+    tkq = min(kq, KQ_TILE)
+    kq = _round_up(kq, tkq)
     flat = (jnp.clip(cell, 0, ncell - 1) * s_max
             + jnp.clip(sub, 0, s_max - 1))
     q = flat // 128
@@ -55,7 +58,8 @@ def batched_subbin_hist(cell, sub, weights, ncell: int, s_max: int, *,
         q = jnp.pad(q, pad)
         r = jnp.pad(r, pad)
         w = jnp.pad(w, pad)               # zero weight => no contribution
-    out = batched_subbin_hist_pallas(q, r, w, kq, tn=tn,
+    out = batched_subbin_hist_pallas(q[:, None], r[:, None], w[:, None],
+                                     kq, tkq=tkq, tn=tn,
                                      interpret=bool(interpret))
     out = out.reshape(p, kq * 128)[:, :k].reshape(p, ncell, s_max)
     return out.astype(weights.dtype)

@@ -20,7 +20,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+
+# Block indices must be int32: under ``jax_enable_x64`` (which ``repro.core``
+# turns on) a bare ``0`` in an index map traces as int64, and Mosaic then
+# fails to lower the kernel.
+_I0 = np.int32(0)
+
+# Full f32 contraction. Mosaic's default for f32 operands is one bf16 pass,
+# which on a v5e left answers up to 1e-2 relative off the f64 reference;
+# H holds counts in the thousands, which bf16's 8-bit mantissa rounds.
+_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _kernel(h_ref, beta_ref, hx_ref, fold_ref, out_ref):
@@ -33,9 +44,11 @@ def _kernel(h_ref, beta_ref, hx_ref, fold_ref, out_ref):
     hx = hx_ref[0]                         # (1, K2)
     fold = fold_ref[0]                     # (K1, K2)
     v = jax.lax.dot_general(beta, hmat, (((1,), (1,)), ((), ())),
+                            precision=_PRECISION,
                             preferred_element_type=jnp.float32)  # (1, K2)
     p_row = jnp.clip(v / jnp.maximum(hx, 1e-30), 0.0, 1.0)
     p1 = jax.lax.dot_general(p_row, fold, (((1,), (1,)), ((), ())),
+                             precision=_PRECISION,
                              preferred_element_type=jnp.float32)  # (1, K1)
     out_ref[...] *= p1
 
@@ -50,9 +63,11 @@ def _batched_kernel(h_ref, beta_ref, hx_ref, fold_ref, out_ref):
     hx = hx_ref[0]                         # (1, K2)
     fold = fold_ref[0]                     # (K1, K2)
     v = jax.lax.dot_general(beta, hmat, (((1,), (1,)), ((), ())),
+                            precision=_PRECISION,
                             preferred_element_type=jnp.float32)  # (Q, K2)
     p_row = jnp.clip(v / jnp.maximum(hx, 1e-30), 0.0, 1.0)
     p1 = jax.lax.dot_general(p_row, fold, (((1,), (1,)), ((), ())),
+                             precision=_PRECISION,
                              preferred_element_type=jnp.float32)  # (Q, K1)
     out_ref[...] *= p1
 
@@ -77,12 +92,12 @@ def batched_weightings_pallas(h_stack, beta, fold, hx, interpret: bool = True):
         _batched_kernel,
         grid=(el,),
         in_specs=[
-            pl.BlockSpec((1, k2, k2), lambda l: (l, 0, 0)),
-            pl.BlockSpec((1, q, k2), lambda l: (l, 0, 0)),
-            pl.BlockSpec((1, 1, k2), lambda l: (l, 0, 0)),
-            pl.BlockSpec((1, k1, k2), lambda l: (l, 0, 0)),
+            pl.BlockSpec((1, k2, k2), lambda l: (l, _I0, _I0)),
+            pl.BlockSpec((1, q, k2), lambda l: (l, _I0, _I0)),
+            pl.BlockSpec((1, 1, k2), lambda l: (l, _I0, _I0)),
+            pl.BlockSpec((1, k1, k2), lambda l: (l, _I0, _I0)),
         ],
-        out_specs=pl.BlockSpec((q, k1), lambda l: (0, 0)),
+        out_specs=pl.BlockSpec((q, k1), lambda l: (_I0, _I0)),
         out_shape=jax.ShapeDtypeStruct((q, k1), jnp.float32),
         interpret=interpret,
     )(h_stack, beta, hx2, fold)
@@ -102,12 +117,12 @@ def fused_weightings_pallas(h_stack, beta, fold, hx, interpret: bool = True):
         _kernel,
         grid=(el,),
         in_specs=[
-            pl.BlockSpec((1, k2, k2), lambda l: (l, 0, 0)),
-            pl.BlockSpec((1, 1, k2), lambda l: (l, 0, 0)),
-            pl.BlockSpec((1, 1, k2), lambda l: (l, 0, 0)),
-            pl.BlockSpec((1, k1, k2), lambda l: (l, 0, 0)),
+            pl.BlockSpec((1, k2, k2), lambda l: (l, _I0, _I0)),
+            pl.BlockSpec((1, 1, k2), lambda l: (l, _I0, _I0)),
+            pl.BlockSpec((1, 1, k2), lambda l: (l, _I0, _I0)),
+            pl.BlockSpec((1, k1, k2), lambda l: (l, _I0, _I0)),
         ],
-        out_specs=pl.BlockSpec((1, k1), lambda l: (0, 0)),
+        out_specs=pl.BlockSpec((1, k1), lambda l: (_I0, _I0)),
         out_shape=jax.ShapeDtypeStruct((1, k1), jnp.float32),
         interpret=interpret,
     )(h_stack, beta2, hx2, fold)

@@ -356,8 +356,11 @@ class FaultMetrics:
     entering quarantine), ``deadline_expired`` (futures resolved with
     ``DeadlineExceeded``), ``decode_retries`` (cold decode attempts
     retried after a failure), plus supporting ``exec_retries`` (waves
-    re-run after an execution failure) and ``worker_restarts`` is
-    reported by the admission queue itself.
+    re-run after an execution failure) and ``wave_errors`` (waves whose
+    batched execution raised and were re-run item by item; on a chip a
+    non-zero count means fused launches failed and their queries were
+    answered without the kernel). ``worker_restarts`` is reported by the
+    admission queue itself.
     """
 
     def __init__(self):
@@ -367,6 +370,7 @@ class FaultMetrics:
         self.n_deadline_expired = 0
         self.n_decode_retries = 0
         self.n_exec_retries = 0
+        self.n_wave_errors = 0
 
     def record_query_error(self):
         """One future resolved with a typed ``QueryError`` result."""
@@ -393,6 +397,11 @@ class FaultMetrics:
         with self._lock:
             self.n_exec_retries += 1
 
+    def record_wave_error(self):
+        """One wave whose batched execution raised (items re-run alone)."""
+        with self._lock:
+            self.n_wave_errors += 1
+
     def snapshot(self) -> dict:
         """Point-in-time fault-counter dict."""
         with self._lock:
@@ -402,6 +411,7 @@ class FaultMetrics:
                 "deadline_expired": self.n_deadline_expired,
                 "decode_retries": self.n_decode_retries,
                 "exec_retries": self.n_exec_retries,
+                "wave_errors": self.n_wave_errors,
             }
 
 

@@ -228,7 +228,9 @@ class AQPServer:
                  trace_enabled: bool = False, trace_buffer: int = 65536,
                  slow_query_ms: float = 100.0,
                  max_engine_bytes: int = 0, demote_idle_s: float = 0.0):
-        self.catalog = catalog or TableCatalog()
+        # An empty catalog is falsy (it has __len__): test for None, or a
+        # server handed a not-yet-filled catalog would silently get its own.
+        self.catalog = catalog if catalog is not None else TableCatalog()
         self.max_engine_bytes = int(max_engine_bytes)
         self.demote_idle_s = float(demote_idle_s)
         self.tracer = Tracer(capacity=trace_buffer, enabled=trace_enabled)
@@ -960,7 +962,15 @@ class AQPServer:
         t_exec0 = time.perf_counter()
         try:
             scheduled = self.scheduler.execute(items)
-        except Exception:
+        except Exception as exc:
+            # Counted, never silent: each item re-runs alone, below
+            # min_group, so a fused-launch failure would otherwise look
+            # like a healthy server that never launches its kernel.
+            self.metrics.faults.record_wave_error()
+            if self.tracer.enabled:
+                self.tracer.instant("wave_error", track="faults",
+                                    attrs={"items": len(items),
+                                           "error": repr(exc)})
             scheduled = [None] * len(items)
             for k, item in enumerate(items):
                 try:

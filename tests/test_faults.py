@@ -207,6 +207,7 @@ def test_kernel_fault_isolates_to_per_item_fallback(framework):
     assert [r.as_tuple() for r in got] == want
     flt = srv.stats()["totals"]["faults"]
     assert flt["query_errors"] == 0    # isolation, not failure
+    assert flt["wave_errors"] >= 1     # ...but counted, never silent
     srv.close()
 
 

@@ -80,6 +80,7 @@ def test_batched_hist2d_integer_counts_exact():
 
 @pytest.mark.parametrize("p,n,ncell,s_max", [
     (1, 100, 9, 8), (3, 500, 64, 16), (2, 2048, 256, 32), (4, 1000, 100, 5),
+    (2, 3000, 3000, 32),        # KQ spans two KQ tiles, padded up to them
 ])
 def test_batched_subbin_hist_matches_ref(p, n, ncell, s_max):
     """Sub-bin Pallas kernel (base-128 flat-id one-hot matmul) == oracle."""

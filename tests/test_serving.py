@@ -82,6 +82,20 @@ def test_unknown_table_raises_plan_error(frameworks):
     assert "logs" in msg and "sensors" in msg
 
 
+def test_servers_share_an_empty_catalog(frameworks):
+    """A catalog handed over before any table is registered is the one the
+    server serves from (an empty catalog is falsy)."""
+    cat = TableCatalog()
+    srv = AQPServer(catalog=cat, mode="numpy")
+    ref = AQPServer(catalog=cat, mode="numpy")
+    assert srv.catalog is cat and ref.catalog is cat
+    srv.register("sensors", frameworks["sensors"])
+    sql = "SELECT COUNT(a) FROM sensors WHERE b > 100"
+    assert ref.query(sql).as_tuple() == srv.query(sql).as_tuple()
+    srv.close()
+    ref.close()
+
+
 def test_catalog_resolve_and_epoch(frameworks):
     cat = TableCatalog()
     cat.register("sensors", frameworks["sensors"])
