@@ -94,9 +94,10 @@ def main():
     for stage in ("plan", "admit", "queue", "assemble", "execute", "resolve"):
         print(f"  {stage:>9}: {exp[f'{stage}_ms']:8.3f} ms")
     print(f"  {'total':>9}: {exp['total_ms']:8.3f} ms  "
-          f"(kernel share {exp['kernel_share_ms']:.3f} ms, "
+          f"(latency_s {res.latency_s * 1e3:.3f} ms, "
           f"plan_cache_hit={exp['plan_cache_hit']}, "
-          f"batched={exp['batched']}, wave={exp['wave_size']})")
+          f"batched={exp['batched']}, wave={exp['wave']} "
+          f"of size {exp['wave_size']})")
 
     print("\n== GROUP BY rides the batched path (per-category leaf plans) ==")
     res = srv.query("SELECT AVG(arr_delay) FROM flights "

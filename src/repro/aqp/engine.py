@@ -148,6 +148,7 @@ class AQPFramework:
             "build_pairs_s": stats.get("pair_phase_s", 0.0),
             "build_pair_mode": stats.get("mode", ""),
             "build_phase_s": dict(stats.get("phase_s", {})),
+            "build_timeline": list(stats.get("timeline", [])),
             "build_from_compressed": bool(stats.get("from_compressed")),
         })
         return self
@@ -170,6 +171,7 @@ class AQPFramework:
             "build_pairs_s": stats.get("pair_phase_s", 0.0),
             "build_pair_mode": stats.get("mode", ""),
             "build_phase_s": dict(stats.get("phase_s", {})),
+            "build_timeline": list(stats.get("timeline", [])),
             "build_from_compressed": True,
         })
         return self

@@ -357,14 +357,19 @@ def build_pairs_compact(sample: np.ndarray, hists: list, params,
     every device relaunch becomes a ``compact_launch`` interval carrying
     its drained/escalated/resumed counters plus ``rung_escalation`` and
     ``occupancy_rebucket`` markers — the per-round schedule ledger as an
-    event stream instead of summed scalars.
+    event stream instead of summed scalars — the host presorts become
+    ``pair_presort`` intervals, and each rung's metadata launch, through
+    its transfer back to the host, a ``pair_metadata`` interval.
     """
     K2 = params.k2_cap
     n_s, d = sample.shape
     keys = _pair_keys(d)
     sample_nn = np.nan_to_num(sample, nan=0.0)
     nanmask = np.isnan(sample)
+    t_sort = time.perf_counter() if timeline is not None else 0.0
     ranks = _column_ranks(sample_nn)
+    if timeline is not None:
+        timeline.add("pair_presort", t_sort, time.perf_counter(), d=d)
     slots = _pow2_floor(int(params.pair_chunk))
     group_cap = slots * _COMPACT_QUEUE
     occupancy = float(params.occupancy_min)
@@ -376,6 +381,7 @@ def build_pairs_compact(sample: np.ndarray, hists: list, params,
     for start in range(0, len(keys), group_cap):
         part = keys[start:start + group_cap]
         g = len(part)
+        t_sort = time.perf_counter() if timeline is not None else 0.0
         x = np.empty((g, n_s), np.float64)
         y = np.empty((g, n_s), np.float64)
         valid = np.empty((g, n_s), bool)
@@ -391,6 +397,9 @@ def build_pairs_compact(sample: np.ndarray, hists: list, params,
             kx0g[p] = min(int(hists[a].k), K2)
             ky0g[p] = min(int(hists[b].k), K2)
         pres = _presort_pairs_host(x, y, valid, rx, ry)
+        if timeline is not None:
+            timeline.add("pair_presort", t_sort, time.perf_counter(),
+                         pairs=g)
 
         # Per-pair capacity rungs: each pair starts at the smallest ladder
         # rung that fits ITS initial grids (the fixed-chunk path levels a
@@ -516,11 +525,15 @@ def build_pairs_compact(sample: np.ndarray, hists: list, params,
                 ex_m[p, : fex.size] = fex
                 ey_m[p, : fey.size] = fey
                 kx_m[p], ky_m[p] = fkx, fky
+            t_meta = time.perf_counter() if timeline is not None else 0.0
             meta = refine.pair_metadata_batch(
                 *data, jnp.asarray(ex_m), jnp.asarray(ey_m),
                 jnp.asarray(kx_m), jnp.asarray(ky_m), k2=cap,
                 use_pallas=params.use_pallas)
             meta_h = jax.device_get(meta)
+            if timeline is not None:
+                timeline.add("pair_metadata", t_meta, time.perf_counter(),
+                             cap=cap, pairs=len(gids))
             for p, gid in enumerate(gids):
                 a, b = part[gid]
                 raw_pairs[(a, b)] = _trim_pair(

@@ -17,11 +17,14 @@ reference path (repro.core.weightings), which is also the oracle in tests.
 """
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 from repro.core import coverage as covlib
 from repro.core import weightings as wlib
 from repro.kernels.weightings import batched_weightings, fused_weightings
+from repro.obs.trace import NOOP_SPAN
 
 Z_98 = wlib.Z_98
 
@@ -69,10 +72,15 @@ class FastPath:
     columns), NOT on the query literals — they are device-resident constants
     of the synopsis. We cache them per column set (on TPU they'd simply stay
     in HBM/VMEM); per query only the tiny beta vectors are assembled.
+
+    ``tracer`` (an optional ``repro.obs.trace.Tracer``): when enabled,
+    ``batch`` records its three parts on the "worker" lane — ``betas``,
+    ``launch`` (with the launch's logical shapes) and ``widen``.
     """
 
-    def __init__(self, use_pallas: bool = True):
+    def __init__(self, use_pallas: bool = True, tracer=None):
         self.use_pallas = use_pallas
+        self.tracer = tracer
 
     # ----------------------------------------------------------- shared stacks
 
@@ -232,7 +240,19 @@ class FastPath:
         elementwise products outside the kernel). Returns a list of
         (w, wlo, whi) triples aligned with ``trees``, or None if any tree is
         ineligible (caller falls back to per-query execution).
+
+        Traced, it records three spans that tile the call: ``betas``
+        (leaf split, stack fetch, beta assembly), ``launch`` (the kernel
+        call through the copy back to the host, the only wait for the
+        device; attrs ``queries``, ``variants``, ``k1``, ``k2max`` and
+        ``pairs``, each pair's ``H`` shape) and ``widen`` (per-query
+        products and bound widening). With ``annotate_jax`` a
+        ``jax.profiler.TraceAnnotation`` named ``aqp.fused:<col>`` covers
+        ``launch``.
         """
+        tracer = self.tracer
+        tracing = tracer is not None and tracer.enabled
+        t0 = time.perf_counter() if tracing else 0.0
         splits = []
         pair_cols = None
         for tree in trees:
@@ -259,12 +279,30 @@ class FastPath:
             betas = self._pair_betas_batch(
                 ph, agg_col, [pls for _, pls in splits], k2max)  # (B,3,L,K2)
             flat = betas.reshape(nq * 3, len(pair_cols), k2max)
-            prob1 = np.asarray(batched_weightings(
-                hpad, flat, fpad, hxpad,
-                use_pallas=self.use_pallas))[:, :k1c]
+            t1 = time.perf_counter() if tracing else 0.0
+            annotation = NOOP_SPAN
+            if tracing and tracer.annotate_jax:
+                import jax.profiler
+                annotation = jax.profiler.TraceAnnotation(
+                    f"aqp.fused:{agg_col}")
+            with annotation:
+                prob1 = np.asarray(batched_weightings(
+                    hpad, flat, fpad, hxpad,
+                    use_pallas=self.use_pallas))[:, :k1c]
             prob1 = prob1.reshape(nq, 3, k1c)               # (B, 3, K1)
+            if tracing:
+                t2 = time.perf_counter()
+                tracer.add("betas", t0, t1, track="worker")
+                tracer.add("launch", t1, t2, track="worker", attrs={
+                    "queries": nq, "variants": 3, "k1": k1c,
+                    "k2max": k2max,
+                    "pairs": [tuple(int(n) for n in ph.pair(agg_col, j)
+                                    .H.shape) for j in pair_cols]})
         else:
             prob1 = np.ones((nq, 3, int(hist.k)))
+            if tracing:               # no pair predicate: nothing to launch
+                t2 = time.perf_counter()
+                tracer.add("betas", t0, t2, track="worker")
 
         out = []
         for qi, (same_col, _) in enumerate(splits):
@@ -275,6 +313,8 @@ class FastPath:
                     w = w * prob
                 triple.append(w)
             out.append(_widen_clip(*triple, ph, h, corrected))
+        if tracing:
+            tracer.add("widen", t2, time.perf_counter(), track="worker")
         return out
 
 

@@ -194,7 +194,7 @@ class QueryTrace:
     __slots__ = ("qid", "t_submit", "t_planned", "t_admitted", "t_drained",
                  "t_exec0", "t_exec1", "t_resolved", "plan_cache_hit",
                  "result_cache_hit", "plan_path", "drain_cause", "wave_size",
-                 "kernel_share_s", "batched", "retries", "rejected")
+                 "wave", "batched", "retries", "rejected")
 
     def __init__(self, t_submit: float | None = None):
         self.qid = next(_QID)
@@ -214,7 +214,9 @@ class QueryTrace:
         self.plan_path = None
         self.drain_cause = None
         self.wave_size = 0
-        self.kernel_share_s = 0.0
+        # Id of the admission wave that executed this query: the ``wave``
+        # attr of that wave's spans on the worker lane.
+        self.wave = None
         self.batched = False
         self.retries = 0
         self.rejected = False
@@ -228,9 +230,8 @@ class QueryTrace:
         """The EXPLAIN breakdown: per-stage milliseconds + flags.
 
         ``plan/admit/queue/assemble/execute/resolve`` tile the full
-        submit -> resolve interval (``total_ms``); ``kernel_share_ms`` is
-        this query's amortized share of its fused wave/kernel launch time
-        (informational — already contained inside ``execute_ms``).
+        submit -> resolve interval (``total_ms``); ``wave`` names the
+        admission wave that executed the query.
         """
         out = {"qid": self.qid}
         prev = self.t_submit
@@ -243,12 +244,12 @@ class QueryTrace:
             total += t - prev
             prev = t
         out["total_ms"] = total * 1e3
-        out["kernel_share_ms"] = self.kernel_share_s * 1e3
         out["plan_cache_hit"] = self.plan_cache_hit
         out["result_cache_hit"] = self.result_cache_hit
         out["plan_path"] = self.plan_path
         out["batched"] = self.batched
         out["wave_size"] = self.wave_size
+        out["wave"] = self.wave
         out["drain_cause"] = self.drain_cause
         out["stale_retries"] = self.retries
         out["rejected"] = self.rejected
@@ -264,6 +265,7 @@ class QueryTrace:
             attrs["sql"] = label
         if self.plan_path is not None:
             attrs["plan_path"] = self.plan_path
+        stage_attrs = {"plan": attrs, "execute": {"wave": self.wave}}
         prev = self.t_submit
         for stage, field in _STAGES:
             t = getattr(self, field)
@@ -271,5 +273,5 @@ class QueryTrace:
                 continue
             if t > prev:
                 tracer.add(stage, prev, t, cat="query", track=track,
-                           attrs=attrs if stage == "plan" else None)
+                           attrs=stage_attrs.get(stage))
             prev = t

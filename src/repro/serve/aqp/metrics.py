@@ -72,6 +72,8 @@ class TableMetrics:
         self.n_queries = 0          # executed (cache misses)
         self.n_batched = 0          # executed via the fused batched kernel
         self.n_fallback = 0         # executed via the per-query path
+        # ... split by why it ran unfused (ScheduledResult.fallback)
+        self.n_fallback_by = {"lone": 0, "unfusable": 0, "declined": 0}
         self.n_result_hits = 0      # served straight from the result cache
         self.n_group_queries = 0    # GROUP BY queries answered
         self.n_leaves_executed = 0  # GROUP BY leaves actually executed
@@ -88,8 +90,10 @@ class TableMetrics:
         # _t_last so cache hits don't stretch the qps window.
         self._t_activity = None
 
-    def record(self, latency_s: float, batched: bool):
-        """One executed query: its latency share and whether it fused."""
+    def record(self, latency_s: float, batched: bool,
+               fallback: str | None = None):
+        """One executed query: its latency share, whether it fused and, if
+        not, why (``ScheduledResult.fallback``)."""
         now = time.perf_counter()
         with self._lock:
             self._t_first = self._t_first if self._t_first is not None else now
@@ -100,6 +104,8 @@ class TableMetrics:
                 self.n_batched += 1
             else:
                 self.n_fallback += 1
+                if fallback is not None:
+                    self.n_fallback_by[fallback] += 1
             self._lat.add(latency_s)
 
     def record_result_hit(self):
@@ -163,6 +169,8 @@ class TableMetrics:
                 "queries_executed": n_queries,
                 "batched": self.n_batched,
                 "fallback": self.n_fallback,
+                **{f"fallback_{why}": n
+                   for why, n in self.n_fallback_by.items()},
                 "result_cache_hits": self.n_result_hits,
                 "batched_fraction": (self.n_batched / n_queries
                                      if n_queries else 0.0),

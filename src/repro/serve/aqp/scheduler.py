@@ -39,6 +39,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures
 import dataclasses
+import itertools
 import threading
 import time
 
@@ -61,12 +62,19 @@ class ScheduledResult:
             (a rebuild landed mid-wave), so the plan was NOT executed — its
             literal encodings belong to a synopsis that no longer exists.
             The caller must re-plan and retry (``AQPServer`` re-enqueues).
+        fallback: why an executed query ran unfused: ``"lone"`` (its
+            plan-shape group was smaller than ``min_group``),
+            ``"unfusable"`` (its plan has no shape key, or the scheduler
+            has no fused path: numpy mode), ``"declined"``
+            (``FastPath.batch`` refused the group). None when batched or
+            stale.
     """
 
     result: QueryResult | None
     batched: bool           # executed via the fused batched launch
     latency_s: float        # per-query wall share (group wall / group size)
     stale: bool = False     # epoch moved mid-wave: not executed, re-plan
+    fallback: str | None = None
 
 
 @dataclasses.dataclass
@@ -83,12 +91,14 @@ class DrainStats:
         depth: queue depth observed at drain time (``size`` plus whatever
             stayed behind because of ``max_batch``).
         waited_s: age of the oldest drained submission (enqueue -> drain).
+        wave: the wave's id, counting from 1 per admission queue.
     """
 
     cause: str
     size: int
     depth: int
     waited_s: float
+    wave: int
 
 
 SHED_POLICIES = ("reject", "shed_oldest", "block")
@@ -182,8 +192,8 @@ class StreamingAdmission:
         # execute_cb returns, never concurrently with one, and exceptions
         # are swallowed so housekeeping can't kill the drain loop.
         self.idle_cb = idle_cb
-        # Optional repro.obs.trace.Tracer: each drain emits an instant on
-        # the "admission" lane (cause/size/depth/oldest-wait).
+        # Optional repro.obs.trace.Tracer: each wave records a ``wave`` span
+        # and its ``hold`` child on the "worker" lane (see _collect/_loop).
         self.tracer = tracer
         self.max_wait_ms = float(max_wait_ms)
         self.max_batch = int(max_batch)
@@ -201,6 +211,7 @@ class StreamingAdmission:
         self._flush = False
         self._stop = False
         self._thread: threading.Thread | None = None
+        self._waves = itertools.count(1)
 
     # ----------------------------------------------------------------- public
 
@@ -310,11 +321,15 @@ class StreamingAdmission:
         return qdl
 
     def _collect(self):
-        """Block until a wave is due; returns (pairs, DrainStats) or None.
+        """Block until a wave is due; returns (pairs, DrainStats, t_hold)
+        or None.
 
         ``pairs`` keeps the ``(t_submit, item)`` tuples so a crashing
         worker can restore un-executed items to the queue front with their
-        original submit times intact.
+        original submit times intact. ``t_hold`` is when the worker first
+        saw the queue non-empty (None when not tracing): from then to the
+        drain it held the wave open by policy, which the ``hold`` span
+        records. Waiting on an empty queue is not recorded.
         """
         with self._cv:
             while not self._q:
@@ -322,6 +337,8 @@ class StreamingAdmission:
                 if self._stop:
                     return None
                 self._cv.wait()
+            t_hold = (time.perf_counter() if self.tracer is not None
+                      and self.tracer.enabled else None)
             # Admission policy: the wave fires on whichever of max_batch /
             # flush / oldest-waited-max_wait_ms trips first — or early,
             # with cause "deadline", when a queued item's per-query
@@ -357,20 +374,18 @@ class StreamingAdmission:
             waited = now - self._q[0][0]
             pairs = [self._q.popleft() for _ in range(take)]
             self._cv.notify_all()   # wake producers blocked on a full queue
-        stats = DrainStats(cause, take, depth, waited)
-        if self.tracer is not None and self.tracer.enabled:
-            self.tracer.instant(
-                "drain", track="admission",
-                attrs={"cause": cause, "size": take, "depth": depth,
-                       "oldest_wait_ms": waited * 1e3})
-        return pairs, stats
+        stats = DrainStats(cause, take, depth, waited, next(self._waves))
+        if t_hold is not None:
+            self.tracer.add("hold", t_hold, now, track="worker",
+                            attrs={"wave": stats.wave})
+        return pairs, stats, t_hold
 
     def _loop(self):
         while True:
             wave = self._collect()
             if wave is None:
                 return
-            pairs, stats = wave
+            pairs, stats, t_hold = wave
             try:
                 faults.hook("worker")
             except Exception:
@@ -402,6 +417,14 @@ class StreamingAdmission:
                 # and the watchdog replaces the worker for queued items.
                 self._revive(())
                 raise
+            if t_hold is not None:
+                # The whole wave, hold to the end of execute_cb: the server
+                # records its assemble/execute/resolve children inside it.
+                self.tracer.add(
+                    "wave", t_hold, time.perf_counter(), track="worker",
+                    attrs={"wave": stats.wave, "cause": stats.cause,
+                           "size": stats.size, "depth": stats.depth,
+                           "oldest_wait_ms": stats.waited_s * 1e3})
             if self.idle_cb is not None:
                 try:
                     self.idle_cb()
@@ -437,12 +460,12 @@ class BatchScheduler:
         max_group: hard cap on queries per fused launch (group splits).
         min_group: groups smaller than this skip the fused launch (a batch
             of one gains nothing from the kernel but still pays dispatch).
-        tracer: optional ``repro.obs.trace.Tracer``. When enabled, every
-            fused launch records a ``kernel`` span on the "worker" lane —
-            fenced with ``jax.block_until_ready`` so the interval is wall
-            time, not dispatch time — and (``tracer.annotate_jax``) opens a
-            matching ``jax.profiler.TraceAnnotation`` so the span lines up
-            inside a captured JAX profiler trace.
+        tracer: optional ``repro.obs.trace.Tracer``, shared with the
+            ``FastPath``. When enabled, every fused group records a
+            ``fused`` span on the "worker" lane around ``FastPath.batch``,
+            which records its ``betas`` / ``launch`` / ``widen`` children.
+            No span adds a device fence: ``launch`` ends where ``batch``
+            already waits for the device (its copy back to the host).
     """
 
     def __init__(self, catalog, mode: str | None = None,
@@ -460,11 +483,13 @@ class BatchScheduler:
         self.min_group = int(min_group)
         self.tracer = tracer
         self.fastpath = (None if mode == "numpy"
-                         else FastPath(use_pallas=(mode == "pallas")))
+                         else FastPath(use_pallas=(mode == "pallas"),
+                                       tracer=tracer))
 
     # ----------------------------------------------------------------- public
 
-    def execute(self, items: list[tuple]) -> list[ScheduledResult]:
+    def execute(self, items: list[tuple],
+                wave: int | None = None) -> list[ScheduledResult]:
         """Execute a wave of planned queries; returns results aligned with
         ``items``. Grouping is transparent: results are identical (numpy
         mode) / fp-close (kernel modes) to per-query execution.
@@ -481,25 +506,28 @@ class BatchScheduler:
         that engine stays correct even if a rebuild lands mid-execution —
         the result is consistent at the plan's epoch and is cached under
         it. A mismatched snapshot returns ``stale=True`` for that item
-        (nothing executes) and the caller re-plans."""
+        (nothing executes) and the caller re-plans.
+
+        Each unfused result says why in ``ScheduledResult.fallback``;
+        ``wave`` (the admission wave's id) labels the ``fused`` spans."""
         out: list[ScheduledResult | None] = [None] * len(items)
         groups: dict[tuple, list[int]] = {}
         for idx, item in enumerate(items):
             table, plan = item[0], item[1]
             shape = plan.shape_key() if self.fastpath is not None else None
             if shape is None:
-                self._run_single(items, idx, out)
+                self._run_single(items, idx, out, "unfusable")
             else:
                 groups.setdefault((table,) + shape, []).append(idx)
 
         for (table, exec_col, _cols), idxs in groups.items():
             if len(idxs) < self.min_group:
                 for idx in idxs:
-                    self._run_single(items, idx, out)
+                    self._run_single(items, idx, out, "lone")
                 continue
             for lo in range(0, len(idxs), self.max_group):
                 self._run_group(items, table, exec_col,
-                                idxs[lo:lo + self.max_group], out)
+                                idxs[lo:lo + self.max_group], out, wave)
         return out  # type: ignore[return-value]
 
     # ---------------------------------------------------------------- helpers
@@ -516,7 +544,8 @@ class BatchScheduler:
     def _tracing(self) -> bool:
         return self.tracer is not None and self.tracer.enabled
 
-    def _run_single(self, items, idx, out, span: bool = True):
+    def _run_single(self, items, idx, out, fallback: str,
+                    span: bool = True):
         item = items[idx]
         table, plan, epoch = item[0], item[1], self._item_epoch(item)
         engine, cur = self.catalog.snapshot(table)
@@ -529,9 +558,9 @@ class BatchScheduler:
         if span and self._tracing():
             self.tracer.add("single_exec", t0, t1, track="worker",
                             attrs={"table": table})
-        out[idx] = ScheduledResult(res, False, t1 - t0)
+        out[idx] = ScheduledResult(res, False, t1 - t0, fallback=fallback)
 
-    def _run_group(self, items, table, exec_col, idxs, out):
+    def _run_group(self, items, table, exec_col, idxs, out, wave=None):
         engine, cur = self.catalog.snapshot(table)
         live = []
         for idx in idxs:
@@ -542,45 +571,29 @@ class BatchScheduler:
                 live.append(idx)
         if not live:
             return
-        ph = engine.ph
         tracing = self._tracing()
         t0 = time.perf_counter()
-        triples = None
-        if len(live) > 0 and self.fastpath is not None:
-            faults.hook("kernel_launch")
-            trees = [items[idx][1].tree for idx in live]
-            if tracing and self.tracer.annotate_jax:
-                import jax.profiler
-                with jax.profiler.TraceAnnotation(
-                        f"aqp.fused:{table}.{exec_col}"):
-                    triples = self.fastpath.batch(ph, exec_col, trees,
-                                                  engine.corrected)
-            else:
-                triples = self.fastpath.batch(ph, exec_col, trees,
-                                              engine.corrected)
-            if tracing and triples is not None:
-                # Fence the fused launch so the kernel span is honest wall
-                # time; the per-query aggregation below would otherwise
-                # absorb the async dispatch.
-                import jax
-                jax.block_until_ready(triples)
-                self.tracer.add("kernel", t0, time.perf_counter(),
-                                track="worker",
-                                attrs={"table": table, "col": exec_col,
-                                       "queries": len(live)})
+        faults.hook("kernel_launch")
+        trees = [items[idx][1].tree for idx in live]
+        triples = self.fastpath.batch(engine.ph, exec_col, trees,
+                                      engine.corrected)
         if triples is None:       # ineligible after all: per-query fallback
             # One group_exec span for the whole loop, not one per item:
             # GROUP BY leaves land here ~10 at a time and per-leaf spans
             # were the single largest traced-path cost (ring churn included)
             # for zero extra information — the leaves are interchangeable.
             for idx in live:
-                self._run_single(items, idx, out, span=False)
+                self._run_single(items, idx, out, "declined", span=False)
             if tracing:
                 self.tracer.add("group_exec", t0, time.perf_counter(),
                                 track="worker",
                                 attrs={"table": table, "col": exec_col,
                                        "queries": len(live)})
             return
+        if tracing:
+            self.tracer.add("fused", t0, time.perf_counter(), track="worker",
+                            attrs={"table": table, "col": exec_col,
+                                   "queries": len(live), "wave": wave})
         for triple, idx in zip(triples, live):
             res = engine.execute_plan(items[idx][1], weightings=triple)
             out[idx] = ScheduledResult(res, True, 0.0)

@@ -867,6 +867,11 @@ class AQPServer:
         Locking: metrics and cache puts take the short state lock; the
         re-plan, the scheduler execution and the future resolution all run
         outside it, so submitters are never blocked behind a wave.
+
+        Traced, the wave's ``assemble`` (drain to scheduler entry),
+        ``execute`` (the scheduler call) and ``resolve`` (to the last
+        future) spans land on the "worker" lane inside the admission
+        worker's ``wave`` span, each with the wave's id.
         """
         # Drained items are worker-owned now; clearing the requeue flag
         # FIRST means a wave-level crash (including the injected
@@ -885,6 +890,7 @@ class AQPServer:
                 sub.trace.t_drained = now
                 sub.trace.drain_cause = drain.cause
                 sub.trace.wave_size = drain.size
+                sub.trace.wave = drain.wave
         # Per-query deadlines: a submission whose deadline passed while it
         # sat in the queue skips the fused launch entirely and resolves
         # with a typed DeadlineExceeded result.
@@ -961,7 +967,7 @@ class AQPServer:
         errors: dict[int, Exception] = {}
         t_exec0 = time.perf_counter()
         try:
-            scheduled = self.scheduler.execute(items)
+            scheduled = self.scheduler.execute(items, drain.wave)
         except Exception as exc:
             # Counted, never silent: each item re-runs alone, below
             # min_group, so a fused-launch failure would otherwise look
@@ -974,7 +980,8 @@ class AQPServer:
             scheduled = [None] * len(items)
             for k, item in enumerate(items):
                 try:
-                    scheduled[k] = self.scheduler.execute([item])[0]
+                    scheduled[k] = self.scheduler.execute([item],
+                                                          drain.wave)[0]
                 except Exception as exc:       # isolate the poisoned item
                     errors[k] = exc
         t_exec1 = time.perf_counter()
@@ -1088,7 +1095,6 @@ class AQPServer:
                 # in-flight duplicates are served copies.
                 if tr is not None:
                     tr.t_exec0, tr.t_exec1 = t_exec0, t_exec1
-                    tr.kernel_share_s = result.latency_s
                     tr.batched = batched
                     tr.retries = sub.retries
                     tr.t_resolved = time.perf_counter()
@@ -1099,6 +1105,14 @@ class AQPServer:
                     futures[0].set_result(result)
                 for fut in futures[1:]:
                     fut.set_result(dataclasses.replace(result, latency_s=0.0))
+        if self.tracer.enabled:
+            wave = {"wave": drain.wave}
+            self.tracer.add("assemble", now, t_exec0, track="worker",
+                            attrs=wave)
+            self.tracer.add("execute", t_exec0, t_exec1, track="worker",
+                            attrs=wave)
+            self.tracer.add("resolve", t_exec1, time.perf_counter(),
+                            track="worker", attrs=wave)
 
     def _resolve_expired(self, subs: list):
         """Resolve deadline-expired submissions with typed
@@ -1214,7 +1228,8 @@ class AQPServer:
     def _finish_single(self, sub: _Submission, sr) -> QueryResult:
         """Cache + account one executed plain query (state lock held)."""
         self.result_cache.put(sub.norm, sub.table, sub.epoch, sr.result)
-        self.metrics.table(sub.table).record(sr.latency_s, sr.batched)
+        self.metrics.table(sub.table).record(sr.latency_s, sr.batched,
+                                             sr.fallback)
         return sr.result
 
     def _finish_group(self, sub: _Submission, executed: dict,
@@ -1222,13 +1237,15 @@ class AQPServer:
         """Cache executed leaves + the pre-assembled group result, account
         (state lock held; the assembly itself ran unlocked)."""
         batched = False
+        fallback = None
         for i, sr in executed.items():
             self.result_cache.put(_leaf_key(sub.plan.leaf_plans[i]),
                                   sub.table, sub.epoch, sr.result)
             batched = batched or sr.batched
+            fallback = fallback or sr.fallback
         self.result_cache.put(sub.norm, sub.table, sub.epoch, result)
         tm = self.metrics.table(sub.table)
-        tm.record(result.latency_s, batched)
+        tm.record(result.latency_s, batched, fallback)
         tm.record_group_expansion(len(executed), len(sub.cached_leaves))
 
     # ------------------------------------------------------------------- stats

@@ -185,7 +185,7 @@ def test_trace_export_valid_trace_event_json(framework):
         assert {"plan", "execute", "resolve"} <= names
         # every query lane is named via M metadata
         meta = [ev for ev in parsed if ev["ph"] == "M"]
-        assert {m["args"]["name"] for m in meta} >= {"admission"}
+        assert {m["args"]["name"] for m in meta} >= {"worker"}
     finally:
         srv.close()
 

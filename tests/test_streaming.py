@@ -286,11 +286,11 @@ def test_rebuild_mid_wave_execution_requeues_and_replans():
     real_execute = srv.scheduler.execute
     fired = []
 
-    def racing_execute(items):
+    def racing_execute(items, wave=None):
         if not fired:                 # first wave only: simulate the race
             fired.append(True)
             fw.rebuild(bigger)        # lands inside the wave, post pre-check
-        return real_execute(items)
+        return real_execute(items, wave)
 
     srv.scheduler.execute = racing_execute
     res = srv.query("SELECT COUNT(*) FROM t WHERE a >= 0")
@@ -314,14 +314,14 @@ def test_stale_requeue_bypasses_block_backpressure():
     real_execute = srv.scheduler.execute
     fired, extra = [], []
 
-    def racing(items):
+    def racing(items, wave=None):
         if not fired:
             fired.append(True)
             # fill the bounded queue to its limit, then move the epoch:
             # the wave item's requeue now meets a FULL queue
             extra.append(srv.submit("SELECT COUNT(*) FROM t WHERE a >= 1"))
             fw.rebuild(bigger)
-        return real_execute(items)
+        return real_execute(items, wave)
 
     srv.scheduler.execute = racing
     fut = srv.submit("SELECT COUNT(*) FROM t WHERE a >= 0")
@@ -341,9 +341,9 @@ def test_stale_retry_bound_fails_futures():
     srv = _server(fw, max_wait_ms=1.0)
     real_execute = srv.scheduler.execute
 
-    def always_racing(items):
+    def always_racing(items, wave=None):
         fw.rebuild(table)             # epoch moves inside every wave
-        return real_execute(items)
+        return real_execute(items, wave)
 
     srv.scheduler.execute = always_racing
     fut = srv.submit("SELECT COUNT(*) FROM t WHERE a >= 0")
