@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import time
 
+import jax
 import numpy as np
 
 from repro.core import coverage as covlib
@@ -81,6 +82,8 @@ class FastPath:
     def __init__(self, use_pallas: bool = True, tracer=None):
         self.use_pallas = use_pallas
         self.tracer = tracer
+        # Pallas interprets off the TPU; asked once here, not per launch.
+        self.interpret = use_pallas and jax.default_backend() != "tpu"
 
     # ----------------------------------------------------------- shared stacks
 
@@ -243,9 +246,10 @@ class FastPath:
 
         Traced, it records three spans that tile the call: ``betas``
         (leaf split, stack fetch, beta assembly), ``launch`` (the kernel
-        call through the copy back to the host, the only wait for the
-        device; attrs ``queries``, ``variants``, ``k1``, ``k2max`` and
-        ``pairs``, each pair's ``H`` shape) and ``widen`` (per-query
+        call through the copy back to the host: one dispatch, which also
+        stages the betas, and one copy of the padded result, the only wait
+        for the device; attrs ``queries``, ``variants``, ``k1``, ``k2max``
+        and ``pairs``, each pair's ``H`` shape) and ``widen`` (per-query
         products and bound widening). With ``annotate_jax`` a
         ``jax.profiler.TraceAnnotation`` named ``aqp.fused:<col>`` covers
         ``launch``.
@@ -286,9 +290,9 @@ class FastPath:
                 annotation = jax.profiler.TraceAnnotation(
                     f"aqp.fused:{agg_col}")
             with annotation:
-                prob1 = np.asarray(batched_weightings(
-                    hpad, flat, fpad, hxpad,
-                    use_pallas=self.use_pallas))[:, :k1c]
+                prob1 = batched_weightings(
+                    hpad, flat, fpad, hxpad, use_pallas=self.use_pallas,
+                    interpret=self.interpret)[:, :k1c]
             prob1 = prob1.reshape(nq, 3, k1c)               # (B, 3, K1)
             if tracing:
                 t2 = time.perf_counter()

@@ -1,4 +1,5 @@
-"""Jitted wrapper for the fused weightings kernel: pad + dispatch."""
+"""Host wrappers for the fused weightings kernels: pad, dispatch and,
+for the query-batched launch, the copy back."""
 from __future__ import annotations
 
 import jax
@@ -67,9 +68,23 @@ def fused_weightings(h_stack, beta, fold, hx, *, use_pallas: bool = True,
     return out[:k1]
 
 
+def _f32(x, shape=None):
+    """``x`` as float32, zero-padded up to ``shape``. Returned as it is
+    where it already is both, as ``FastPath._get_stack``'s device-resident
+    stacks are: then no op is dispatched for it."""
+    shape = tuple(x.shape) if shape is None else shape
+    if x.dtype == np.float32 and tuple(x.shape) == shape:
+        return x
+    x = jnp.asarray(x, jnp.float32)
+    if tuple(x.shape) != shape:
+        x = jnp.pad(x, [(0, n - m) for n, m in zip(shape, x.shape)])
+    return x
+
+
 def batched_weightings(h_stack, beta, fold, hx, *, use_pallas: bool = True,
-                       interpret: bool | None = None):
-    """Query-batched fused weightings: beta (Q, L, K2) -> (Q, K1).
+                       interpret: bool | None = None) -> np.ndarray:
+    """Query-batched fused weightings: beta (Q, L, K2) -> (Q, K1), as a
+    host NumPy array.
 
     See ref.batched_weightings_ref for semantics. Q is bucketed to a power
     of two (``q_bucket``: UP to the next pow-2, min 8) so ragged serving
@@ -78,36 +93,34 @@ def batched_weightings(h_stack, beta, fold, hx, *, use_pallas: bool = True,
     multiples. Padding is value-safe: padded beta rows produce garbage rows
     that are sliced away; padded K entries are zero.
 
-    ``beta`` is per-wave host data and is padded in NumPy (one device
-    transfer, no dispatched pad ops on the hot path); the shared
-    h/fold/hx stacks should already be device-resident and 128-padded
-    (``FastPath._get_stack``) — if not, they are padded here once.
+    One launch is one device round trip: ``beta`` is padded on the host and
+    handed to the jitted kernel as a NumPy array, so the dispatch makes the
+    only host-to-device copy; the whole padded ``(q_bucket(Q), K1p)`` result
+    comes back in one copy and is sliced on the host. The h/fold/hx stacks
+    should already be device-resident float32 and 128-padded
+    (``FastPath._get_stack``), and then pass through untouched; others are
+    converted and padded here, with ops of their own. ``interpret`` None
+    asks ``jax.default_backend()``; a caller that launches often decides it
+    once and passes it.
     """
     beta = np.asarray(beta, np.float32)
     q, el, k2 = beta.shape
     k1 = fold.shape[1]
     qp = q_bucket(q)
-    k2p = _round_up(k2, 128)
-    k1p = _round_up(k1, 128)
-    if use_pallas and interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    h_stack = jnp.asarray(h_stack, jnp.float32)
-    fold = jnp.asarray(fold, jnp.float32)
-    hx = jnp.asarray(hx, jnp.float32)
-    pad_k = (k2p, k1p) != (k2, k1) and use_pallas
-    if pad_k:
-        h_stack = jnp.pad(h_stack, ((0, 0), (0, k2p - k2), (0, k2p - k2)))
-        hx = jnp.pad(hx, ((0, 0), (0, k2p - k2)))
-        fold = jnp.pad(fold, ((0, 0), (0, k1p - k1), (0, k2p - k2)))
 
     if not use_pallas:
         bpad = np.zeros((qp, el, k2), np.float32)
         bpad[:q] = beta
-        return _batched_ref_jit(h_stack, jnp.asarray(bpad), fold, hx)[:q]
+        out = _batched_ref_jit(_f32(h_stack), bpad, _f32(fold), _f32(hx))
+        return np.asarray(out)[:q]
 
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    k2p = _round_up(k2, 128)
+    k1p = _round_up(k1, 128)
     bpad = np.zeros((el, qp, k2p), np.float32)
     bpad[:, :q, :k2] = np.swapaxes(beta, 0, 1)
-    out = batched_weightings_pallas(h_stack, jnp.asarray(bpad), fold, hx,
-                                    interpret=bool(interpret))
-    return out[:q, :k1]
+    out = batched_weightings_pallas(
+        _f32(h_stack, (el, k2p, k2p)), bpad, _f32(fold, (el, k1p, k2p)),
+        _f32(hx, (el, k2p)), interpret=bool(interpret))
+    return np.asarray(out)[:q, :k1]

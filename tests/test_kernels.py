@@ -7,9 +7,11 @@ from repro.kernels.hist2d import batched_hist2d, hist2d
 from repro.kernels.hist2d.ref import batched_hist2d_ref, hist2d_ref
 from repro.kernels.subbin import batched_subbin_hist
 from repro.kernels.subbin.ref import batched_subbin_hist_ref
-from repro.kernels.weightings import batched_weightings, fused_weightings
+from repro.kernels.weightings import (batched_weightings, fused_weightings,
+                                      q_bucket)
 from repro.kernels.weightings.ref import (batched_weightings_ref,
                                           fused_weightings_ref)
+from repro.kernels.weightings.weightings import batched_weightings_pallas
 
 
 @pytest.mark.parametrize("n,ki,kj", [
@@ -173,12 +175,49 @@ def test_fused_weightings_matches_ref(el, k2, k1):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("q,el,k2,k1", [
-    (1, 1, 16, 16), (5, 3, 70, 90), (17, 2, 200, 260), (64, 4, 128, 128),
+def _padded_device(x, shape):
+    """``x`` zero-padded to ``shape`` and put on the device, as
+    ``FastPath._get_stack`` keeps a synopsis's stacks."""
+    out = np.zeros(shape, np.float32)
+    out[tuple(slice(0, n) for n in x.shape)] = x
+    return jnp.asarray(out)
+
+
+def _parent_launch(H, beta, fold, hx):
+    """The launch as it was before it became one round trip: every input
+    staged and padded on the device, the result sliced there and copied."""
+    q, el, k2 = beta.shape
+    k1 = fold.shape[1]
+    k2p, k1p = -(-k2 // 128) * 128, -(-k1 // 128) * 128
+    bpad = np.zeros((el, q_bucket(q), k2p), np.float32)
+    bpad[:, :q, :k2] = np.swapaxes(beta, 0, 1)
+    out = batched_weightings_pallas(
+        _padded_device(np.asarray(H), (el, k2p, k2p)), jnp.asarray(bpad),
+        _padded_device(np.asarray(fold), (el, k1p, k2p)),
+        _padded_device(np.asarray(hx), (el, k2p)), interpret=True)
+    return np.asarray(out[:q, :k1])
+
+
+# (q, el, k2, k1, stacks): "host" stacks are unpadded NumPy, "device" ones
+# are 128-padded on the device, with beta padded to match, as FastPath
+# hands them over.
+@pytest.mark.parametrize("q,el,k2,k1,stacks", [
+    pytest.param(1, 1, 16, 16, "host", id="1-1-16-16"),
+    pytest.param(5, 3, 70, 90, "host", id="5-3-70-90"),
+    pytest.param(17, 2, 200, 260, "host", id="17-2-200-260"),
+    pytest.param(64, 4, 128, 128, "host", id="64-4-128-128"),
+    (1, 1, 16, 16, "device"),
+    (3, 2, 100, 130, "device"),
+    (9, 3, 70, 90, "host"),
+    (33, 1, 130, 200, "device"),
+    (129, 2, 200, 260, "host"),
+    (300, 3, 150, 300, "device"),
+    (300, 1, 250, 70, "host"),
 ])
-def test_batched_weightings_matches_per_query(q, el, k2, k1):
+def test_batched_weightings_matches_per_query(q, el, k2, k1, stacks):
     """Query-batched kernel == per-query oracle, row by row, for both the
-    Pallas path and the jitted-jnp path."""
+    Pallas path and the jitted-jnp path; the Pallas launch returns a host
+    array bit-identical to the kernel's device-sliced result."""
     rng = np.random.default_rng(q * k2 + el)
     H = (rng.random((el, k2, k2)) * 10).astype(np.float32)
     hx = H.sum(2) + 1.0
@@ -190,11 +229,113 @@ def test_batched_weightings_matches_per_query(q, el, k2, k1):
     seq = np.stack([np.asarray(fused_weightings_ref(
         jnp.asarray(H), jnp.asarray(beta[qi]), jnp.asarray(fold),
         jnp.asarray(hx))) for qi in range(q)])
+    args = (H, beta, fold, hx)
+    if stacks == "device":
+        k2p, k1p = -(-k2 // 128) * 128, -(-k1 // 128) * 128
+        args = (_padded_device(H, (el, k2p, k2p)),
+                np.pad(beta, ((0, 0), (0, 0), (0, k2p - k2))),
+                _padded_device(fold, (el, k1p, k2p)),
+                _padded_device(hx, (el, k2p)))
     for use_pallas in (True, False):
-        out = np.asarray(batched_weightings(H, beta, fold, hx,
-                                            use_pallas=use_pallas))
-        assert out.shape == (q, k1)
-        np.testing.assert_allclose(out, seq, rtol=1e-5, atol=1e-6)
+        out = batched_weightings(*args, use_pallas=use_pallas)
+        assert isinstance(out, np.ndarray)
+        assert out.shape == (q, args[2].shape[1])
+        np.testing.assert_allclose(out[:, :k1], seq, rtol=1e-5, atol=1e-6)
+        if use_pallas:
+            np.testing.assert_array_equal(out, _parent_launch(*args))
+
+
+class _CopyOnly:
+    """Stands for the kernel's device result: the launch may copy it to the
+    host once (``__array__``) and do nothing else with it."""
+
+    def __init__(self, out):
+        self._out = out
+        self.copies = 0
+
+    def __array__(self, dtype=None, copy=None):
+        self.copies += 1
+        return np.asarray(self._out)
+
+    def __getattr__(self, name):
+        if name.startswith("__array"):     # NumPy probing its protocols
+            raise AttributeError(name)
+        raise AssertionError(f"launch touched its device result: .{name}")
+
+    def __getitem__(self, key):
+        raise AssertionError("launch sliced its device result on the device")
+
+
+def test_batched_launch_is_one_round_trip(monkeypatch):
+    """A launch over FastPath's device-resident padded stacks dispatches
+    the jitted kernel once and nothing else: the stacks pass through
+    untouched, the betas reach it as host data (staged by the dispatch
+    alone), and the result is copied back once and sliced on the host."""
+    from repro.kernels.weightings import ops
+
+    rng = np.random.default_rng(3)
+    el, k2p, k1p, k1, q = 2, 256, 512, 500, 6
+    H = _padded_device(rng.random((el, 200, 200)) * 10, (el, k2p, k2p))
+    fold = _padded_device(np.eye(k1, 200)[None].repeat(el, 0),
+                          (el, k1p, k2p))
+    hx = _padded_device(rng.random((el, 200)) + 1, (el, k2p))
+    beta = np.zeros((q, el, k2p), np.float32)
+    beta[..., :200] = rng.random((q, el, 200))
+    calls = []
+
+    def spy(h_stack, bpad, fold_, hx_, interpret):
+        calls.append((h_stack, bpad, fold_, hx_))
+        res = _CopyOnly(batched_weightings_pallas(h_stack, bpad, fold_, hx_,
+                                                  interpret=interpret))
+        calls.append(res)
+        return res
+
+    monkeypatch.setattr(ops, "batched_weightings_pallas", spy)
+    out = ops.batched_weightings(H, beta, fold, hx, interpret=True)
+    (h_in, b_in, f_in, x_in), res = calls
+    assert h_in is H and f_in is fold and x_in is hx
+    assert type(b_in) is np.ndarray
+    assert b_in.shape == (el, q_bucket(q), k2p) and b_in.dtype == np.float32
+    assert res.copies == 1
+    assert type(out) is np.ndarray and out.shape == (q, k1p)
+    np.testing.assert_array_equal(out, _parent_launch(H, beta, fold, hx))
+
+
+def test_fastpath_batch_launches_once(synopsis, monkeypatch):
+    """FastPath.batch makes one kernel launch per plan-shape group, with
+    its cached device stacks as they are, and decides interpret mode when
+    it is built, not per launch."""
+    import jax
+
+    from repro.core.fastpath import FastPath
+    from repro.core.query import QueryEngine
+    from repro.kernels.weightings import ops
+
+    fp = FastPath(use_pallas=True)
+    eng = QueryEngine(synopsis)
+    trees = [eng.plan_sql(f"SELECT SUM(c0) FROM t WHERE c1 > {250 + 9 * i}"
+                          f" AND c2 < {950 - 11 * i}").tree
+             for i in range(5)]
+    want = FastPath(use_pallas=False).batch(synopsis, 0, trees, False)
+    calls = []
+
+    def spy(*args, interpret):
+        calls.append((args, interpret))
+        return batched_weightings_pallas(*args, interpret=interpret)
+
+    def no_backend_query():
+        raise AssertionError("interpret mode asked per launch")
+
+    monkeypatch.setattr(ops, "batched_weightings_pallas", spy)
+    monkeypatch.setattr(jax, "default_backend", no_backend_query)
+    got = fp.batch(synopsis, 0, trees, corrected=False)
+    (args, interpret), = calls
+    stack = synopsis._fastpath_stacks[(0, (1, 2))]
+    assert args[0] is stack[0] and args[2] is stack[1] and args[3] is stack[2]
+    assert interpret is True
+    for g, w in zip(got, want):
+        for a, b in zip(g, w):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
 
 
 def test_batched_weightings_ref_reduces_to_single():
