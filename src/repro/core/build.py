@@ -709,6 +709,8 @@ def build_pairwise_hist(
                 union.append(pr.ey)
         edges_u = np.unique(np.concatenate(union))
         edges_u = edges_u[np.isfinite(edges_u)]
+        if edges_u.size == 1:  # constant column: keep its zero-width bin
+            edges_u = np.repeat(edges_u, 2)
         if edges_u.size > K1 + 1:  # capacity: thin uniformly, keep extremes
             idx = np.linspace(0, edges_u.size - 1, K1 + 1).round().astype(int)
             edges_u = edges_u[np.unique(idx)]

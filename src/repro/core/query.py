@@ -217,17 +217,26 @@ def assemble_groups(plan: QueryPlan, leaf_results: dict) -> QueryResult:
     """Per-leaf ``QueryResult``s -> one GROUP BY ``QueryResult``.
 
     ``leaf_results`` maps leaf index -> result. Matches the sequential
-    ``_group_by`` contract exactly: a category appears in ``groups`` iff its
-    estimate is non-null and positive. Shared by the engine's own leaf path
-    and the serving layer (which supplies leaf results from the batched
-    kernel launch and the per-leaf result cache).
+    ``_group_by`` contract exactly (``has_group``). Shared by the engine's
+    own leaf path and the serving layer (which supplies leaf results from
+    the batched kernel launch and the per-leaf result cache).
     """
     groups = {}
     for i, value in enumerate(plan.group_values):
         res = leaf_results.get(i)
-        if res is not None and res.estimate is not None and res.estimate > 0:
+        if res is not None and has_group(plan.func, res.estimate):
             groups[value] = res.as_tuple()
     return QueryResult(None, None, None, groups=groups)
+
+
+def has_group(func: str, estimate) -> bool:
+    """Whether a category appears in a GROUP BY answer, as in SQL: its
+    aggregate is non-NULL and, for COUNT, positive. A negative SUM or AVG
+    is a group like any other. An empty selection's SUM estimates 0, so a
+    SUM of 0 counts as no group."""
+    if estimate is None:
+        return False
+    return func not in ("COUNT", "SUM") or estimate != 0
 
 
 # ---------------------------------------------------------------------------
@@ -741,6 +750,6 @@ class QueryEngine:
             leaf = wlib.Leaf(gcol, "=", float(code))
             sub = leaf if tree is None else wlib.Node("and", [leaf, tree])
             res = self._single(func, agg_col, sub)
-            if res.estimate is not None and res.estimate > 0:
+            if has_group(func, res.estimate):
                 groups[value] = res.as_tuple()
         return QueryResult(None, None, None, groups=groups)

@@ -194,7 +194,8 @@ class QueryTrace:
     __slots__ = ("qid", "t_submit", "t_planned", "t_admitted", "t_drained",
                  "t_exec0", "t_exec1", "t_resolved", "plan_cache_hit",
                  "result_cache_hit", "plan_path", "drain_cause", "wave_size",
-                 "wave", "batched", "retries", "rejected")
+                 "wave", "batched", "retries", "rejected", "group_s",
+                 "leaves")
 
     def __init__(self, t_submit: float | None = None):
         self.qid = next(_QID)
@@ -220,6 +221,10 @@ class QueryTrace:
         self.batched = False
         self.retries = 0
         self.rejected = False
+        # GROUP BY only: seconds of the statement's leaf gathering and group
+        # assembly in the wave's resolve, and its leaf count.
+        self.group_s = None
+        self.leaves = None
 
     @property
     def track(self) -> str:
@@ -231,7 +236,9 @@ class QueryTrace:
 
         ``plan/admit/queue/assemble/execute/resolve`` tile the full
         submit -> resolve interval (``total_ms``); ``wave`` names the
-        admission wave that executed the query.
+        admission wave that executed the query. A GROUP BY statement adds
+        ``group_ms`` (its group assembly, a part of ``resolve_ms``) and
+        ``leaves``.
         """
         out = {"qid": self.qid}
         prev = self.t_submit
@@ -253,6 +260,9 @@ class QueryTrace:
         out["drain_cause"] = self.drain_cause
         out["stale_retries"] = self.retries
         out["rejected"] = self.rejected
+        if self.group_s is not None:
+            out["group_ms"] = self.group_s * 1e3
+            out["leaves"] = self.leaves
         return out
 
     def emit_spans(self, tracer: Tracer, label: str = ""):

@@ -78,6 +78,7 @@ class TableMetrics:
         self.n_group_queries = 0    # GROUP BY queries answered
         self.n_leaves_executed = 0  # GROUP BY leaves actually executed
         self.n_leaf_cache_hits = 0  # GROUP BY leaves served from cache
+        self.n_leaves_fused = 0     # ... executed in a fused launch
         self.n_cold_decodes = 0     # cold-tier blob -> engine decodes
         self.cold_synopsis_bytes = 0  # registered blob size (cold tables)
         self.cold_decode_ms = None  # latest cold-start decode latency
@@ -117,12 +118,15 @@ class TableMetrics:
             self._t_activity = now
             self.n_result_hits += 1
 
-    def record_group_expansion(self, n_executed: int, n_cached: int):
-        """One GROUP BY query: leaves executed vs served from cache."""
+    def record_group_expansion(self, n_executed: int, n_cached: int,
+                               n_fused: int = 0):
+        """One GROUP BY query: leaves executed vs served from cache, and of
+        the executed, how many rode a fused launch."""
         with self._lock:
             self.n_group_queries += 1
             self.n_leaves_executed += int(n_executed)
             self.n_leaf_cache_hits += int(n_cached)
+            self.n_leaves_fused += int(n_fused)
 
     def record_cold_register(self, n_bytes: int):
         """A cold (storage-tier) table registered under this name: its
@@ -180,6 +184,9 @@ class TableMetrics:
                     "queries": self.n_group_queries,
                     "leaves_executed": self.n_leaves_executed,
                     "leaf_cache_hits": self.n_leaf_cache_hits,
+                    "leaves_fused": self.n_leaves_fused,
+                    "leaves_unfused": (self.n_leaves_executed
+                                       - self.n_leaves_fused),
                 },
             }
             if self.n_cold_decodes or self.cold_synopsis_bytes:
@@ -280,7 +287,7 @@ class AdmissionMetrics:
 # ``plan_template_hit`` (zero-parse template bind / plan-cache hit)
 # according to its ``plan_path`` label.
 _STAGE_KEYS = ("plan", "admit", "queue", "assemble", "execute", "resolve",
-               "plan_template_hit", "plan_full")
+               "group", "plan_template_hit", "plan_full")
 
 
 class StageMetrics:

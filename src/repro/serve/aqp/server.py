@@ -1022,6 +1022,7 @@ class AQPServer:
         # the futures list: any duplicate attached before the pop is
         # resolved here, any submit after it plans afresh. Pure group
         # assembly runs unlocked too.
+        tracing = self.tracer.enabled
         for sub in batch:
             tr = sub.trace
             if id(sub) in stale:
@@ -1049,6 +1050,8 @@ class AQPServer:
             result = None
             batched = False
             if err is None and sub.plan.leaf_plans:
+                if tracing:
+                    t_group0 = time.perf_counter()
                 executed = leaf_out.get(id(sub), {})
                 leaf_results = dict(sub.cached_leaves)
                 leaf_results.update({i: sr.result
@@ -1057,6 +1060,9 @@ class AQPServer:
                 result.latency_s = sum(sr.latency_s
                                        for sr in executed.values())
                 batched = any(sr.batched for sr in executed.values())
+                if tracing:
+                    self._trace_group(sub, executed, result, drain.wave,
+                                      t_group0)
             with self._state_lock:
                 # Conditional pop: deadline-carrying submissions never
                 # register in the dedupe map, so an unconditional pop could
@@ -1113,6 +1119,20 @@ class AQPServer:
                             attrs=wave)
             self.tracer.add("resolve", t_exec1, time.perf_counter(),
                             track="worker", attrs=wave)
+
+    def _trace_group(self, sub: _Submission, executed: dict,
+                     result: QueryResult, wave: int, t0: float):
+        """Record one GROUP BY statement's group assembly (traced only): a
+        ``group`` span on the worker lane and the ``group`` stage of its
+        explain."""
+        t1 = time.perf_counter()
+        leaves = len(sub.plan.leaf_plans)
+        self.tracer.add("group", t0, t1, track="worker", attrs={
+            "wave": wave, "leaves": leaves, "executed": len(executed),
+            "cached": len(sub.cached_leaves), "groups": len(result.groups)})
+        if sub.trace is not None:
+            sub.trace.group_s = t1 - t0
+            sub.trace.leaves = leaves
 
     def _resolve_expired(self, subs: list):
         """Resolve deadline-expired submissions with typed
@@ -1236,17 +1256,18 @@ class AQPServer:
                       result: QueryResult):
         """Cache executed leaves + the pre-assembled group result, account
         (state lock held; the assembly itself ran unlocked)."""
-        batched = False
+        fused = 0
         fallback = None
         for i, sr in executed.items():
             self.result_cache.put(_leaf_key(sub.plan.leaf_plans[i]),
                                   sub.table, sub.epoch, sr.result)
-            batched = batched or sr.batched
+            fused += sr.batched
             fallback = fallback or sr.fallback
         self.result_cache.put(sub.norm, sub.table, sub.epoch, result)
         tm = self.metrics.table(sub.table)
-        tm.record(result.latency_s, batched, fallback)
-        tm.record_group_expansion(len(executed), len(sub.cached_leaves))
+        tm.record(result.latency_s, fused > 0, fallback)
+        tm.record_group_expansion(len(executed), len(sub.cached_leaves),
+                                  fused)
 
     # ------------------------------------------------------------------- stats
 

@@ -143,3 +143,27 @@ def test_seed_from_bases_off_still_correct(gd_setup):
     assert ph.build_stats["from_compressed"] is True
     res = QueryEngine(ph).query("SELECT COUNT(*) FROM t WHERE c1 > 300")
     assert res.estimate is not None and res.estimate > 0
+
+
+@pytest.mark.parametrize("where, want", [
+    ("year >= 2015", 1.0), ("year = 2015", 1.0), ("year < 2016", 1.0),
+    ("year > 2015", 0.0), ("year < 2015", 0.0),
+    ("x >= 0 AND year = 2015", None)])
+def test_constant_column_keeps_its_rows(where, want):
+    """A column with one value (the flights table's ``year``) keeps a bin
+    holding every row, so a predicate on it selects all rows or none."""
+    rng = np.random.default_rng(0)
+    n = 6_000
+    table = {"year": np.full(n, 2015.0),
+             "x": rng.normal(size=n).round(2),
+             "g": np.array(["p", "q"])[rng.integers(0, 2, n)]}
+    from repro.aqp.engine import AQPFramework
+
+    fw = AQPFramework(BuildParams(n_samples=3_000, seed=1),
+                      use_compression=True).ingest(table)
+    got = fw.query(f"SELECT COUNT(*) FROM t WHERE {where}").estimate
+    if want is None:
+        want = fw.query("SELECT COUNT(*) FROM t WHERE x >= 0").estimate / n
+    assert got == pytest.approx(want * n)
+    assert fw.query("SELECT AVG(year) FROM t WHERE x >= 0").estimate \
+        == pytest.approx(2015.0)

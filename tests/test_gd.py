@@ -35,6 +35,23 @@ def test_preprocess_categorical_frequency_ranked():
     assert info.encode("zzz") != info.encode("zzz")  # NaN: unseen literal
 
 
+@pytest.mark.parametrize("values, codes, categories", [
+    (np.array(["b", "a", "b", "c", "a", "b"]), [0, 1, 0, 2, 1, 0],
+     ("b", "a", "c")),
+    (np.array(["x", None, "y", float("nan"), "y", 3], dtype=object),
+     [2, np.nan, 0, np.nan, 0, 1], ("y", "3", "x")),
+    (np.array([None, None], dtype=object), [np.nan, np.nan], ()),
+    (np.array([b"q", b"p", b"q"]), [0, 1, 0], ("b'q'", "b'p'")),
+])
+def test_preprocess_categorical_codes_and_nulls(values, codes, categories):
+    """Codes rank categories by frequency, ties in sorted order; None and
+    NaN in an object column are NULL; other values count by ``str``."""
+    got, info = preprocess_column(values, "x")
+    np.testing.assert_array_equal(got, np.asarray(codes, float))
+    assert info.kind == "categorical"
+    assert info.categories == categories
+
+
 def test_preprocess_missing():
     codes, info = preprocess_column(np.array([1.0, np.nan, 3.0]), "x")
     assert np.isnan(codes[1])
