@@ -42,6 +42,40 @@ def _slice_beta(ph, leaf, h, u, vmin, vmax, mu):
     return beta, blo, bhi
 
 
+def _leaf_key(leaf):
+    """The inputs of a leaf's coverage triple on a slice of its column:
+    leaves with equal keys get the same triple, bit for bit. Literals key
+    by ``float.hex``, exact to the bit, so 0.0 and -0.0 stay apart."""
+    if isinstance(leaf, wlib.Consolidated):
+        return (leaf.col, tuple((float(lo).hex(), float(hi).hex())
+                                for lo, hi in leaf.intervals))
+    return (leaf.col, leaf.op, float(leaf.value).hex())
+
+
+class _SliceMemo:
+    """One fused launch's same-column triples by leaf, and its tally:
+    ``slices`` the launch needs (one per plan per leaf) against those
+    ``computed``. Lives for one ``FastPath.batch`` call."""
+
+    def __init__(self):
+        self._same_col = {}
+        self.slices = 0
+        self.computed = 0
+
+    def tally(self) -> dict:
+        return {"slices": self.slices, "computed": self.computed}
+
+    def once(self, leaf, evaluate):
+        """``evaluate(leaf)``, evaluated once per distinct same-column
+        leaf of the launch."""
+        key = _leaf_key(leaf)
+        probs = self._same_col.get(key)
+        if probs is None:
+            probs = self._same_col[key] = evaluate(leaf)
+            self.computed += 1
+        return probs
+
+
 def _round_up(x: int, mult: int = 128) -> int:
     return ((x + mult - 1) // mult) * mult
 
@@ -77,6 +111,11 @@ class FastPath:
     ``tracer`` (an optional ``repro.obs.trace.Tracer``): when enabled,
     ``batch`` records its three parts on the "worker" lane — ``betas``,
     ``launch`` (with the launch's logical shapes) and ``widen``.
+
+    ``n_slices`` / ``n_computed`` count, over every launch ``batch`` made,
+    the leaf coverage slices the launches needed and those evaluated: a
+    leaf repeated across a launch's plans (a GROUP BY's shared brush) is
+    evaluated once. ``batch`` runs on the scheduler's one worker thread.
     """
 
     def __init__(self, use_pallas: bool = True, tracer=None):
@@ -84,6 +123,8 @@ class FastPath:
         self.tracer = tracer
         # Pallas interprets off the TPU; asked once here, not per launch.
         self.interpret = use_pallas and jax.default_backend() != "tpu"
+        self.n_slices = 0
+        self.n_computed = 0
 
     # ----------------------------------------------------------- shared stacks
 
@@ -122,22 +163,34 @@ class FastPath:
         cache[key] = entry
         return entry
 
-    def _split_leaves(self, ph, agg_col, tree):
-        """Pure-AND tree -> (same-col beta triples, pair leaves) or None."""
+    def _split_leaves(self, ph, agg_col, tree, memo=None):
+        """Pure-AND tree -> (same-col beta triples, pair leaves) or None.
+
+        With a ``_SliceMemo`` (one launch's), a same-column leaf already
+        met in the launch reuses its clipped triple, and the memo tallies
+        the tree's slices."""
         leaves = wlib.flat_and_leaves(tree)
         if leaves is None:
             return None
         hist = ph.hists[agg_col]
         same_col = [[], [], []]   # per variant: (k1,) probs for j == agg_col
         pair_leaves = []
+
+        def clipped(leaf):
+            triple = _slice_beta(ph, leaf, hist.h, hist.u, hist.vmin,
+                                 hist.vmax, ph.columns[leaf.col].mu)
+            return [np.clip(b, 0.0, 1.0) for b in triple]
+
         for leaf in leaves:
             if leaf.col == agg_col:
-                triple = _slice_beta(ph, leaf, hist.h, hist.u, hist.vmin,
-                                     hist.vmax, ph.columns[leaf.col].mu)
+                probs = (clipped(leaf) if memo is None
+                         else memo.once(leaf, clipped))
                 for idx in range(3):
-                    same_col[idx].append(np.clip(triple[idx], 0.0, 1.0))
+                    same_col[idx].append(probs[idx])
             else:
                 pair_leaves.append(leaf)
+        if memo is not None:
+            memo.slices += len(leaves)
         # Canonical (sorted-column) leaf order: the single and batched paths
         # then share one cached stack per column set regardless of the order
         # predicates appeared in the WHERE clause.
@@ -156,7 +209,7 @@ class FastPath:
                 betas[idx, li, :len(triple[idx])] = triple[idx]
         return betas
 
-    def _pair_betas_batch(self, ph, agg_col, leaf_lists, k2max):
+    def _pair_betas_batch(self, ph, agg_col, leaf_lists, k2max, memo=None):
         """(B, 3, L, K2max) coverage stack for B same-shape queries.
 
         Vectorized per-leaf beta assembly: the B leaves on pair column
@@ -164,14 +217,17 @@ class FastPath:
         stack their literals into ONE broadcasted ``coverage_single`` +
         ``coverage_bounds`` evaluation per (column, operator) group —
         replacing the per-query-per-wave Python calls into ``_pair_betas``.
-        Consolidated (interval-set) leaves keep the per-leaf path; they are
-        the rarity in batched waves. Bit-for-bit equal to stacking
-        ``_pair_betas`` per query (same elementwise arithmetic, broadcast
-        over a leading batch axis).
+        Consolidated (interval-set) leaves are evaluated once per distinct
+        interval set on the column, and their rows copied to every query
+        that carries the same set (a GROUP BY's leaves share the brush).
+        Bit-for-bit equal to stacking ``_pair_betas`` per query (same
+        elementwise arithmetic, broadcast over a leading batch axis).
+        ``memo`` (a ``_SliceMemo``) tallies the slices evaluated.
         """
         nq = len(leaf_lists)
         el = len(leaf_lists[0])
         betas = np.zeros((nq, 3, el, k2max), np.float32)
+        computed = 0
         for li in range(el):
             leaves = [pls[li] for pls in leaf_lists]
             col = leaves[0].col
@@ -181,13 +237,23 @@ class FastPath:
             k = len(np.asarray(h))
             mu = ph.columns[col].mu
             by_op: dict[str, list] = {}
+            first: dict = {}        # interval set -> its first query's row
+            dst, src = [], []
             for qi, leaf in enumerate(leaves):
                 if isinstance(leaf, wlib.Consolidated):
+                    row = first.setdefault(_leaf_key(leaf), qi)
+                    if row != qi:
+                        dst.append(qi)
+                        src.append(row)
+                        continue
                     triple = _slice_beta(ph, leaf, h, u, vmin, vmax, mu)
                     for idx in range(3):
                         betas[qi, idx, li, :k] = triple[idx]
+                    computed += 1
                 else:
                     by_op.setdefault(leaf.op, []).append(qi)
+            if dst:
+                betas[dst, :, li] = betas[src, :, li]
             for op, qis in by_op.items():
                 values = np.array([[leaves[qi].value] for qi in qis],
                                   float)                       # (Bg, 1)
@@ -198,6 +264,9 @@ class FastPath:
                 rows = np.asarray(qis)
                 for idx, arr in enumerate((beta, blo, bhi)):
                     betas[rows, idx, li, :k] = arr
+                computed += len(qis)
+        if memo is not None:
+            memo.computed += computed
         return betas
 
     # ------------------------------------------------------------ single query
@@ -244,8 +313,13 @@ class FastPath:
         (w, wlo, whi) triples aligned with ``trees``, or None if any tree is
         ineligible (caller falls back to per-query execution).
 
+        Each distinct leaf's coverage triple is evaluated once per call and
+        placed in every plan that carries the leaf; ``n_slices`` and
+        ``n_computed`` grow by the call's tally.
+
         Traced, it records three spans that tile the call: ``betas``
-        (leaf split, stack fetch, beta assembly), ``launch`` (the kernel
+        (leaf split, stack fetch, beta assembly; attrs ``slices`` and
+        ``computed``, the call's tally), ``launch`` (the kernel
         call through the copy back to the host: one dispatch, which also
         stages the betas, and one copy of the padded result, the only wait
         for the device; attrs ``queries``, ``variants``, ``k1``, ``k2max``
@@ -257,10 +331,11 @@ class FastPath:
         tracer = self.tracer
         tracing = tracer is not None and tracer.enabled
         t0 = time.perf_counter() if tracing else 0.0
+        memo = _SliceMemo()
         splits = []
         pair_cols = None
         for tree in trees:
-            split = self._split_leaves(ph, agg_col, tree)
+            split = self._split_leaves(ph, agg_col, tree, memo)
             if split is None:
                 return None
             same_col, pair_leaves = split
@@ -281,7 +356,8 @@ class FastPath:
             hpad, fpad, hxpad, k1c, k2max = self._get_stack(
                 ph, agg_col, pair_cols)
             betas = self._pair_betas_batch(
-                ph, agg_col, [pls for _, pls in splits], k2max)  # (B,3,L,K2)
+                ph, agg_col, [pls for _, pls in splits], k2max,
+                memo)                                           # (B,3,L,K2)
             flat = betas.reshape(nq * 3, len(pair_cols), k2max)
             t1 = time.perf_counter() if tracing else 0.0
             annotation = NOOP_SPAN
@@ -296,7 +372,8 @@ class FastPath:
             prob1 = prob1.reshape(nq, 3, k1c)               # (B, 3, K1)
             if tracing:
                 t2 = time.perf_counter()
-                tracer.add("betas", t0, t1, track="worker")
+                tracer.add("betas", t0, t1, track="worker",
+                           attrs=memo.tally())
                 tracer.add("launch", t1, t2, track="worker", attrs={
                     "queries": nq, "variants": 3, "k1": k1c,
                     "k2max": k2max,
@@ -306,8 +383,11 @@ class FastPath:
             prob1 = np.ones((nq, 3, int(hist.k)))
             if tracing:               # no pair predicate: nothing to launch
                 t2 = time.perf_counter()
-                tracer.add("betas", t0, t2, track="worker")
+                tracer.add("betas", t0, t2, track="worker",
+                           attrs=memo.tally())
 
+        self.n_slices += memo.slices
+        self.n_computed += memo.computed
         out = []
         for qi, (same_col, _) in enumerate(splits):
             triple = []

@@ -79,6 +79,8 @@ class TableMetrics:
         self.n_leaves_executed = 0  # GROUP BY leaves actually executed
         self.n_leaf_cache_hits = 0  # GROUP BY leaves served from cache
         self.n_leaves_fused = 0     # ... executed in a fused launch
+        self.n_beta_slices = 0      # leaf slices its fused launches needed
+        self.n_beta_computed = 0    # ... of which evaluated (distinct leaves)
         self.n_cold_decodes = 0     # cold-tier blob -> engine decodes
         self.cold_synopsis_bytes = 0  # registered blob size (cold tables)
         self.cold_decode_ms = None  # latest cold-start decode latency
@@ -127,6 +129,13 @@ class TableMetrics:
             self.n_leaves_executed += int(n_executed)
             self.n_leaf_cache_hits += int(n_cached)
             self.n_leaves_fused += int(n_fused)
+
+    def record_betas(self, n_slices: int, n_computed: int):
+        """One fused launch's beta assembly: the leaf coverage slices its
+        plans needed, and how many it evaluated (each distinct leaf once)."""
+        with self._lock:
+            self.n_beta_slices += int(n_slices)
+            self.n_beta_computed += int(n_computed)
 
     def record_cold_register(self, n_bytes: int):
         """A cold (storage-tier) table registered under this name: its
@@ -187,6 +196,10 @@ class TableMetrics:
                     "leaves_fused": self.n_leaves_fused,
                     "leaves_unfused": (self.n_leaves_executed
                                        - self.n_leaves_fused),
+                },
+                "betas": {
+                    "slices": self.n_beta_slices,
+                    "computed": self.n_beta_computed,
                 },
             }
             if self.n_cold_decodes or self.cold_synopsis_bytes:

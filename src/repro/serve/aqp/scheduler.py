@@ -466,10 +466,14 @@ class BatchScheduler:
             which records its ``betas`` / ``launch`` / ``widen`` children.
             No span adds a device fence: ``launch`` ends where ``batch``
             already waits for the device (its copy back to the host).
+        metrics: optional ``repro.serve.aqp.metrics.Metrics``; each fused
+            launch adds its beta-slice tally (``FastPath.n_slices`` /
+            ``n_computed`` deltas) to its table's ``TableMetrics``.
     """
 
     def __init__(self, catalog, mode: str | None = None,
-                 max_group: int = 256, min_group: int = 2, tracer=None):
+                 max_group: int = 256, min_group: int = 2, tracer=None,
+                 metrics=None):
         if mode is None:
             import jax
             mode = "pallas" if jax.default_backend() == "tpu" else "numpy"
@@ -482,6 +486,7 @@ class BatchScheduler:
         # nothing from the kernel but still pays its dispatch.
         self.min_group = int(min_group)
         self.tracer = tracer
+        self.metrics = metrics
         self.fastpath = (None if mode == "numpy"
                          else FastPath(use_pallas=(mode == "pallas"),
                                        tracer=tracer))
@@ -575,8 +580,9 @@ class BatchScheduler:
         t0 = time.perf_counter()
         faults.hook("kernel_launch")
         trees = [items[idx][1].tree for idx in live]
-        triples = self.fastpath.batch(engine.ph, exec_col, trees,
-                                      engine.corrected)
+        fp = self.fastpath
+        slices, computed = fp.n_slices, fp.n_computed
+        triples = fp.batch(engine.ph, exec_col, trees, engine.corrected)
         if triples is None:       # ineligible after all: per-query fallback
             # One group_exec span for the whole loop, not one per item:
             # GROUP BY leaves land here ~10 at a time and per-leaf spans
@@ -590,6 +596,9 @@ class BatchScheduler:
                                 attrs={"table": table, "col": exec_col,
                                        "queries": len(live)})
             return
+        if self.metrics is not None:
+            self.metrics.table(table).record_betas(
+                fp.n_slices - slices, fp.n_computed - computed)
         if tracing:
             self.tracer.add("fused", t0, time.perf_counter(), track="worker",
                             attrs={"table": table, "col": exec_col,

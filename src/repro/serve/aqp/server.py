@@ -237,10 +237,12 @@ class AQPServer:
         self.slow_query_ms = float(slow_query_ms)
         self._slow_log: collections.deque = collections.deque(
             maxlen=self.SLOW_LOG_CAP)
+        self.metrics = Metrics()
         self.scheduler = BatchScheduler(self.catalog, mode=mode,
                                         max_group=max_group,
                                         min_group=min_group,
-                                        tracer=self.tracer)
+                                        tracer=self.tracer,
+                                        metrics=self.metrics)
         self.admission = StreamingAdmission(self._execute_wave,
                                             max_wait_ms=max_wait_ms,
                                             max_batch=max_batch,
@@ -259,7 +261,6 @@ class AQPServer:
         self.template_cache = LRUCache(template_cache_size)
         self._planner = (PlannerPool(planner_workers)
                          if planner_workers > 0 else None)
-        self.metrics = Metrics()
         self.retry_timeout_s = float(retry_timeout_s)
         self.single_lock = bool(single_lock)
         self._wiring: dict[str, tuple] = {}   # name -> (framework, callback)

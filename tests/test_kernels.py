@@ -396,6 +396,99 @@ def test_pair_betas_batch_bit_for_bit(synopsis):
     np.testing.assert_array_equal(batched, seq)
 
 
+def _brushes(qi, shape):
+    """Pair leaves of GROUP BY leaf ``qi`` (agg column 0): the statement's
+    brush, shared by its leaves, and the leaf's own ``c3 = value``."""
+    from repro.core import weightings as wlib
+    eq = wlib.Leaf(3, "=", float(qi + 1))
+    if shape == "one_brush":
+        return [wlib.Consolidated(1, [(200.0, 350.0)]), eq]
+    if shape == "two_brushes":
+        return [wlib.Consolidated(1, [(200.0, 350.0)]),
+                wlib.Consolidated(2, [(500.0, 1100.0)]), eq]
+    # Repeated and distinct interval sets across the plans.
+    return [wlib.Consolidated(1, [(200.0, 350.0)] if qi % 3
+                              else [(150.0, 260.0), (300.0, 420.0)]),
+            wlib.Consolidated(2, [(100.0 * (qi % 4), 900.0)]), eq]
+
+
+@pytest.mark.parametrize("shape, computed", [
+    ("one_brush", 1 + 14), ("two_brushes", 2 + 14), ("mixed", 2 + 4 + 14)])
+def test_pair_betas_batch_group_by_bit_for_bit(synopsis, shape, computed):
+    """A GROUP BY's 14 leaf plans share their brush: each distinct interval
+    set is evaluated once and copied to the other plans, bit for bit what
+    stacking the per-plan ``_pair_betas`` gives."""
+    from repro.core.fastpath import FastPath, _SliceMemo
+    fp = FastPath(use_pallas=False)
+    leaf_lists = [_brushes(qi, shape) for qi in range(14)]
+    memo = _SliceMemo()
+    batched = fp._pair_betas_batch(synopsis, 0, leaf_lists, 512, memo)
+    seq = np.stack([fp._pair_betas(synopsis, 0, pls, 512)
+                    for pls in leaf_lists])
+    np.testing.assert_array_equal(batched, seq)
+    assert memo.computed == computed
+
+
+def _group_trees(same_col: bool, vary: bool = False):
+    """14 GROUP BY leaf trees on agg column 0: a brush on c0 itself (a
+    same-column leaf) or on c2, one on c1, and each leaf's ``c3 = v``.
+    ``vary`` gives every third tree another c0 brush."""
+    from repro.core import weightings as wlib
+
+    def first(v):
+        if not same_col:
+            return wlib.Consolidated(2, [(500.0, 1100.0)])
+        return wlib.Consolidated(0, [(50.0, 400.0)] if vary and v % 3 == 0
+                                 else [(100.0, 600.0)])
+    return [wlib.Node("and", [first(v),
+                              wlib.Consolidated(1, [(200.0, 350.0)]),
+                              wlib.Leaf(3, "=", float(v))])
+            for v in range(1, 15)]
+
+
+def test_fastpath_batch_memo_is_bit_for_bit(synopsis):
+    """``FastPath.batch`` with same-column brushes repeated across the
+    plans (two distinct ones) gives the triples of the path without the
+    memo: per-plan ``_split_leaves`` and stacked per-plan ``_pair_betas``."""
+    from repro.core.fastpath import FastPath
+    trees = _group_trees(same_col=True, vary=True)
+    fp = FastPath(use_pallas=False)
+    got = fp.batch(synopsis, 0, trees, corrected=False)
+    assert fp.n_computed < fp.n_slices
+
+    plain = FastPath(use_pallas=False)
+    plain._split_leaves = (lambda ph, agg, tree, memo=None:
+                           FastPath._split_leaves(plain, ph, agg, tree))
+    plain._pair_betas_batch = (lambda ph, agg, lls, k2max, memo=None:
+                               np.stack([plain._pair_betas(ph, agg, pls,
+                                                           k2max)
+                                         for pls in lls]))
+    want = plain.batch(synopsis, 0, trees, corrected=False)
+    assert len(got) == len(want) == 14
+    for g, w in zip(got, want):
+        for a, b in zip(g, w):
+            np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("same_col", [False, True])
+def test_fastpath_batch_counts_slices(synopsis, same_col):
+    """14 plans x (2 brushes + 1 ``=``) need 42 slices and evaluate 16:
+    each brush once and each distinct literal once, whether a brush is on
+    the agg column or a pair column; the traced ``betas`` span carries the
+    launch's tally, and the counters add up over launches."""
+    from repro.core.fastpath import FastPath
+    from repro.obs.trace import Tracer
+    tracer = Tracer(enabled=True)
+    fp = FastPath(use_pallas=False, tracer=tracer)
+    trees = _group_trees(same_col)
+    assert fp.batch(synopsis, 0, trees, corrected=False) is not None
+    assert (fp.n_slices, fp.n_computed) == (42, 16)
+    (betas,) = [sp for sp in tracer.spans() if sp.name == "betas"]
+    assert betas.attrs == {"slices": 42, "computed": 16}
+    fp.batch(synopsis, 0, trees[:3], corrected=False)
+    assert (fp.n_slices, fp.n_computed) == (42 + 9, 16 + 5)
+
+
 def test_fastpath_batch_equals_single(synopsis):
     """FastPath.batch (one fused launch + vectorized betas) matches the
     per-query FastPath.__call__ triples."""
