@@ -12,8 +12,8 @@ import sys
 import time
 import traceback
 
-SUITES = ("construction", "kernels", "storage", "serving", "fig8", "fig9",
-          "table5", "table6", "fig11", "roofline")
+SUITES = ("storage", "fig8", "fig9", "table5", "table6", "fig11",
+          "roofline")
 
 
 def main(argv=None):

@@ -39,13 +39,7 @@ Run:
 
     PYTHONPATH=src python examples/serve_aqp.py
 
-Benchmark (throughput vs batch size, cache-hit sweep, streaming p50/p99
-under Poisson arrivals, GROUP BY batching; acceptance targets: >= 5x
-queries/sec at batch 64 and > 2x for GROUP BY at batch 16 vs one-at-a-time
-AQPFramework.query):
-
-    PYTHONPATH=src python -m benchmarks.bench_serving          # quick
-    PYTHONPATH=src python -m benchmarks.run --only serving     # full
+The serving path's benchmark is ``bench/`` on a TPU (docs/benchmarks.md).
 """
 from __future__ import annotations
 

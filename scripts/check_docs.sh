@@ -84,7 +84,7 @@ build_knobs = [k for k in class_fields("src/repro/core/types.py",
 serving_knobs = ["mode", "plan_cache_size", "result_cache_size",
                  "max_result_bytes", "max_group", "min_group",
                  "max_wait_ms", "max_batch", "max_queue_depth",
-                 "shed_policy", "retry_timeout_s", "single_lock",
+                 "shed_policy", "retry_timeout_s",
                  "plan_templates", "template_cache_size", "planner_workers"]
 obs_knobs = ["trace_enabled", "trace_buffer", "slow_query_ms"]
 # Fault-tolerance knobs (per-query deadline + cold decode resilience)

@@ -4,7 +4,7 @@ Layout:
   repro.core      — the paper's contribution (PairwiseHist synopsis + queries)
   repro.gd        — GreedyGD compression substrate
   repro.aqp       — end-to-end AQP engine, datasets, baselines, exact engine
-  repro.kernels   — Pallas TPU kernels (hist2d, fused weightings) + refs
+  repro.kernels   — batched Pallas TPU kernels (hist2d, subbin, weightings) + refs
   repro.models    — 10 assigned LM architectures
   repro.sharding  — logical-axis sharding rules
   repro.train     — optimizer, train step, telemetry, grad compression

@@ -37,10 +37,9 @@ class AQPFramework:
     _epoch_seq = itertools.count(1)
 
     def __init__(self, params: BuildParams | None = None,
-                 use_compression: bool = True, fastpath=None):
+                 use_compression: bool = True):
         self.params = params or BuildParams()
         self.use_compression = use_compression
-        self.fastpath = fastpath
         self.gd = GreedyGD()
         self.compressed = None
         self.preprocessed = None
@@ -137,7 +136,7 @@ class AQPFramework:
             build_input, self.preprocessed.columns, self.params,
             seed_edges=seed_edges)
         t3 = time.perf_counter()
-        engine = QueryEngine(self.synopsis, fastpath=self.fastpath)
+        engine = QueryEngine(self.synopsis)
         # Pair-phase telemetry from the (batched) builder: rebuild() runs
         # through here too, so serving-cache invalidation pauses
         # (append_rows -> rebuild) are dominated by build_pairs_s.
@@ -163,7 +162,7 @@ class AQPFramework:
         self.preprocessed = None
         self.synopsis = build_pairwise_hist(compressed, columns, self.params)
         t1 = time.perf_counter()
-        engine = QueryEngine(self.synopsis, fastpath=self.fastpath)
+        engine = QueryEngine(self.synopsis)
         stats = self.synopsis.build_stats
         self._publish(engine, {
             "preprocess_s": 0.0, "compress_s": 0.0,

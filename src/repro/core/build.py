@@ -9,23 +9,21 @@ Pipeline:
      tensors (bucketed to powers of two so jit compiles a bounded set of
      shapes) and refine level-synchronously on device, with results
      arriving in grouped device->host transfers — no per-pair ``int(kx)`` /
-     ``np.asarray`` round-trips. The default scheduler is
+     ``np.asarray`` round-trips. The scheduler is
      **convergence-compacting** (``build_pairs_compact`` /
      ``refine.refine_2d_compact``): ``pair_chunk`` slots refine a
      device-resident pending queue, draining each pair the round it
      converges and backfilling its slot, so deep-refining (correlated)
      pairs never lockstep-drag shallow ones; per-column presorts are
      shared across all pairs (``_column_ranks``) and capacity-guard
-     escalation re-queues only the capped pairs. The fixed-chunk
-     scheduler (``build_pairs_batched``: one ``lax.while_loop`` per chunk
-     of ``pair_chunk`` pairs, whole-chunk escalation) remains behind
-     ``compact_drain=False``. Per-round bin counts dispatch through
-     ``repro.kernels.hist2d.batched_hist2d`` and chi-squared sub-bin
-     counts through ``repro.kernels.subbin`` (Pallas one-hot matmuls when
-     ``params.use_pallas``; dtype-preserving jnp oracles otherwise). The
-     legacy per-pair host loop survives as ``build_pairs_sequential``
-     (oracle + benchmark baseline; bit-for-bit equal results, asserted in
-     tests/test_build_batched.py and tests/test_build_compact.py).
+     escalation re-queues only the capped pairs. Per-round bin counts
+     dispatch through ``repro.kernels.hist2d.batched_hist2d`` and
+     chi-squared sub-bin counts through ``repro.kernels.subbin`` (Pallas
+     one-hot matmuls when ``params.use_pallas``; dtype-preserving jnp
+     oracles otherwise). The per-pair host loop ``build_pairs_sequential``
+     is the oracle: tests build through ``_build_pairwise_hist`` with it
+     and assert bit-for-bit equal synopses (tests/test_build_batched.py,
+     tests/test_build_compact.py).
 
 Missing values (NaN) are excluded per-histogram: a row missing column i does
 not contribute to hist(i) nor to any pair involving i — matching SQL
@@ -139,11 +137,11 @@ def _trim_pair(ex, ey, kx, ky, H, hx, ux, vminx, vmaxx, hy, uy, vminy,
 
 def build_pairs_sequential(sample: np.ndarray, hists: list, params,
                            crit2, m_pts: int) -> dict:
-    """Legacy per-pair host loop (one compiled function, P sequential
-    launches with a blocking device->host sync per pair).
-
-    Kept as the bit-for-bit oracle for the batched path and as the
-    benchmark baseline. Returns {(a, b): PairHist} without fold maps.
+    """Per-pair host loop (one compiled function, P sequential launches
+    with a blocking device->host sync per pair): the bit-for-bit oracle
+    for ``build_pairs_compact``, reached from tests through
+    ``_build_pairwise_hist``. Returns {(a, b): PairHist} without fold
+    maps.
     """
     K2 = params.k2_cap
     sample_j = jnp.asarray(np.nan_to_num(sample, nan=0.0))
@@ -253,79 +251,6 @@ def _cap_ladder(need: int, k2_cap: int, k2_start: int) -> list[int]:
     return ladder
 
 
-def build_pairs_batched(sample: np.ndarray, hists: list, params,
-                        crit2, m_pts: int, stats: dict | None = None,
-                        timeline: BuildTimeline | None = None) -> dict:
-    """Pair-batched 2-D construction: chunked (P, N) launches, one grouped
-    device->host transfer per chunk. Returns {(a, b): PairHist} (no folds);
-    records per-chunk (size, capacity) launches into ``stats`` and, when a
-    ``timeline`` is passed, one ``batched_launch`` interval per launch.
-
-    Each chunk refines at the smallest capacity rung that fits its initial
-    grids; if any pair's capacity guard binds, the whole chunk re-runs one
-    rung up (results are capacity-independent while the guard is slack, so
-    this is exact — and saturation is the rare case by design).
-    """
-    K2 = params.k2_cap
-    n_s, d = sample.shape
-    keys = _pair_keys(d)
-    sample_nn = np.nan_to_num(sample, nan=0.0)
-    nanmask = np.isnan(sample)
-    # Normalize the chunk cap to a power of two — rounding DOWN, so the
-    # documented memory bound (~ pair_chunk * k2^2 * s2_max) is honoured;
-    # the tail chunk buckets to the next power of two >= its size, so jit
-    # sees at most log2(chunk) + 1 distinct batch shapes per capacity rung.
-    chunk = _pow2_floor(int(params.pair_chunk))
-    launches = []
-    raw_pairs = {}
-    for start in range(0, len(keys), chunk):
-        part = keys[start:start + chunk]
-        size = _pow2_ceil(len(part))
-        x = np.zeros((size, n_s), np.float64)
-        y = np.zeros((size, n_s), np.float64)
-        valid = np.zeros((size, n_s), bool)
-        kx0 = np.ones(size, np.int32)
-        ky0 = np.ones(size, np.int32)
-        for p, (a, b) in enumerate(part):
-            x[p] = sample_nn[:, a]
-            y[p] = sample_nn[:, b]
-            valid[p] = ~(nanmask[:, a] | nanmask[:, b])
-            kx0[p] = min(int(hists[a].k), K2)
-            ky0[p] = min(int(hists[b].k), K2)
-        pres_j = tuple(jnp.asarray(a) for a in
-                       _presort_pairs_host(x, y, valid))
-        need = int(max(kx0.max(), ky0.max()))
-        for cap in _cap_ladder(need, K2, params.k2_start):
-            t_launch = time.perf_counter()
-            ex0 = np.full((size, cap + 1), np.inf, np.float64)
-            ey0 = np.full((size, cap + 1), np.inf, np.float64)
-            ex0[:, :2] = 0.0
-            ey0[:, :2] = 0.0  # dummy lanes: one empty bin, no valid rows
-            for p, (a, b) in enumerate(part):
-                ex0[p] = _pad_edges(hists[a].edges, cap)
-                ey0[p] = _pad_edges(hists[b].edges, cap)
-            out = refine.build_pairs_device(
-                *pres_j, jnp.asarray(ex0), jnp.asarray(ey0),
-                jnp.asarray(kx0), jnp.asarray(ky0),
-                jnp.float64(m_pts), crit2, k2=cap, s_max=params.s2_max,
-                max_rounds=params.max_rounds_2d,
-                use_pallas=params.use_pallas)
-            host = jax.device_get(out)  # ONE grouped transfer for the chunk
-            launches.append((size, cap))
-            if timeline is not None:
-                timeline.add("batched_launch", t_launch, time.perf_counter(),
-                             cap=cap, size=size, pairs=len(part))
-            capped = host[4]
-            if cap >= K2 or not capped[: len(part)].any():
-                break
-        fields = host[:4] + host[5:]    # drop the capped flag
-        for p, (a, b) in enumerate(part):
-            raw_pairs[(a, b)] = _trim_pair(*(v[p] for v in fields))
-    if stats is not None:
-        stats["pair_launches"] = launches
-    return raw_pairs
-
-
 # Pending pairs held device-resident per compacted launch, in units of the
 # slot count: the compaction horizon (a deep pair can only be overlapped by
 # pairs inside its group) and the (group * N) presort-upload memory bound.
@@ -335,16 +260,14 @@ _COMPACT_QUEUE = 4
 def build_pairs_compact(sample: np.ndarray, hists: list, params,
                         crit2, m_pts: int, stats: dict | None = None,
                         timeline: BuildTimeline | None = None) -> dict:
-    """Convergence-compacting 2-D construction (the default batched path).
+    """Convergence-compacting 2-D construction (the served pair phase).
 
     Pairs feed through ``refine.refine_2d_compact`` in groups of up to
     ``_COMPACT_QUEUE`` chunks: ``pair_chunk`` slots refine while the rest
     of the group waits device-resident in the pending queue, so a slot
     whose pair converges is backfilled the same round instead of idling
-    until the chunk's slowest pair finishes (the fixed-chunk
-    ``build_pairs_batched`` failure mode on correlated columns). The
-    capacity ladder escalates *per pair*: only pairs whose guard bound
-    re-queue one rung up, where the fixed-chunk path re-runs whole chunks.
+    until the chunk's slowest pair finishes. The capacity ladder escalates
+    *per pair*: only pairs whose guard bound re-queue one rung up.
     Per-column presorts are shared (``_column_ranks``) and each group's
     metadata runs as one batched launch.
 
@@ -402,8 +325,7 @@ def build_pairs_compact(sample: np.ndarray, hists: list, params,
                          pairs=g)
 
         # Per-pair capacity rungs: each pair starts at the smallest ladder
-        # rung that fits ITS initial grids (the fixed-chunk path levels a
-        # whole chunk up to its widest pair), and capacity-guard escalation
+        # rung that fits ITS initial grids, and capacity-guard escalation
         # re-queues only the capped pairs one rung up.
         ladder = _cap_ladder(2, K2, params.k2_start)
         queue: dict[int, list] = {}
@@ -540,6 +462,7 @@ def build_pairs_compact(sample: np.ndarray, hists: list, params,
                     ex_m[p], ey_m[p], kx_m[p], ky_m[p],
                     *(v[p] for v in meta_h))
     if stats is not None:
+        stats["mode"] = "compact"
         stats["pair_launches"] = launches
         stats["compaction"] = comp
     return raw_pairs
@@ -570,6 +493,15 @@ def build_pairwise_hist(
     The input ``columns`` list is left untouched; the returned synopsis
     carries copies with per-column null counts filled in.
     """
+    return _build_pairwise_hist(data, columns, params, n_rows_full,
+                                seed_edges, build_pairs_compact)
+
+
+def _build_pairwise_hist(data, columns, params, n_rows_full, seed_edges,
+                         build_pairs) -> PairwiseHist:
+    """``build_pairwise_hist`` with its pair phase run by ``build_pairs``,
+    which takes ``build_pairs_compact``'s arguments: that builder when
+    served, the ``build_pairs_sequential`` oracle wrapped to fit in tests."""
     params = params or BuildParams()
     ct = data if isinstance(data, CompressedTable) else None
     if ct is not None:
@@ -668,22 +600,9 @@ def build_pairwise_hist(
     t_pairs = time.perf_counter()
     build_stats: dict = {}
     with timeline.phase("pair_phase"):
-        if params.pair_batched and params.compact_drain:
-            mode = "compact"
-            raw_pairs = build_pairs_compact(sample, hists, params, crit2,
-                                            m_pts, stats=build_stats,
-                                            timeline=timeline)
-        elif params.pair_batched:
-            mode = "batched"
-            raw_pairs = build_pairs_batched(sample, hists, params, crit2,
-                                            m_pts, stats=build_stats,
-                                            timeline=timeline)
-        else:
-            mode = "sequential"
-            raw_pairs = build_pairs_sequential(sample, hists, params, crit2,
-                                               m_pts)
+        raw_pairs = build_pairs(sample, hists, params, crit2, m_pts,
+                                stats=build_stats, timeline=timeline)
     build_stats.update({
-        "mode": mode,
         "n_pairs": len(raw_pairs),
         "pair_phase_s": time.perf_counter() - t_pairs,
         "pair_chunk": params.pair_chunk,

@@ -2,17 +2,15 @@
 
 The paper's execution model runs ~3 small ops per predicate (mat-vec, fold,
 divide) plus a combine — at sub-ms latencies the launch/dispatch overhead
-dominates. This path stacks all AND-ed predicates of a query and executes
-ONE fused kernel per bound variant (estimate / lower / upper).
-
-``FastPath`` additionally exposes a *query-batched* entry (``batch``): a
-group of queries sharing a plan shape (same agg column, same pair-predicate
-column set) executes as ONE launch covering every query and all three bound
-variants — the serving-layer analogue of the per-predicate fusion, used by
-``repro.serve.aqp.scheduler.BatchScheduler``.
+dominates. ``FastPath.batch`` stacks the AND-ed pair predicates of a group
+of queries sharing a plan shape (same agg column, same pair-predicate
+column set) and executes ONE fused kernel launch covering every query and
+all three bound variants (estimate / lower / upper). Its caller is
+``repro.serve.aqp.scheduler.BatchScheduler``, which hands each query's
+triple to ``QueryEngine.execute_plan(plan, weightings=...)``.
 
 Supported: AND trees of leaves (the dominant template in the paper's
-workload). OR / nested trees return None -> engine falls back to the NumPy
+workload). OR / nested trees return None -> the engine answers on the NumPy
 reference path (repro.core.weightings), which is also the oracle in tests.
 """
 from __future__ import annotations
@@ -24,12 +22,10 @@ import numpy as np
 
 from repro.core import coverage as covlib
 from repro.core import weightings as wlib
-from repro.kernels.weightings import batched_weightings, fused_weightings
+from repro.kernels.weightings import batched_weightings
 from repro.obs.trace import NOOP_SPAN
 
 Z_98 = wlib.Z_98
-
-_flat_and_leaves = wlib.flat_and_leaves  # back-compat alias
 
 
 def _slice_beta(ph, leaf, h, u, vmin, vmax, mu):
@@ -101,7 +97,8 @@ def _widen_clip(w, wlo, whi, ph, h, corrected):
 
 
 class FastPath:
-    """Engine hook: (ph, agg_col, tree, corrected) -> weightings triple.
+    """Scheduler hook: (ph, agg_col, trees, corrected) -> one weightings
+    triple per tree, from one fused launch.
 
     The padded (H, fold) stacks depend only on (agg column, predicate
     columns), NOT on the query literals — they are device-resident constants
@@ -191,23 +188,11 @@ class FastPath:
                 pair_leaves.append(leaf)
         if memo is not None:
             memo.slices += len(leaves)
-        # Canonical (sorted-column) leaf order: the single and batched paths
-        # then share one cached stack per column set regardless of the order
-        # predicates appeared in the WHERE clause.
+        # Canonical (sorted-column) leaf order: queries then share one
+        # cached stack per column set regardless of the order predicates
+        # appeared in the WHERE clause.
         pair_leaves.sort(key=lambda lf: lf.col)
         return same_col, pair_leaves
-
-    def _pair_betas(self, ph, agg_col, pair_leaves, k2max):
-        """(3, L, K2max) coverage matrix for one query's pair leaves."""
-        el = len(pair_leaves)
-        betas = np.zeros((3, el, k2max), np.float32)
-        for li, leaf in enumerate(pair_leaves):
-            pr = ph.pair(agg_col, leaf.col)
-            triple = _slice_beta(ph, leaf, pr.hy, pr.uy, pr.vminy,
-                                 pr.vmaxy, ph.columns[leaf.col].mu)
-            for idx in range(3):
-                betas[idx, li, :len(triple[idx])] = triple[idx]
-        return betas
 
     def _pair_betas_batch(self, ph, agg_col, leaf_lists, k2max, memo=None):
         """(B, 3, L, K2max) coverage stack for B same-shape queries.
@@ -215,13 +200,13 @@ class FastPath:
         Vectorized per-leaf beta assembly: the B leaves on pair column
         ``li`` share the slice metadata (h, u, v-, v+), so simple-op leaves
         stack their literals into ONE broadcasted ``coverage_single`` +
-        ``coverage_bounds`` evaluation per (column, operator) group —
-        replacing the per-query-per-wave Python calls into ``_pair_betas``.
+        ``coverage_bounds`` evaluation per (column, operator) group, in
+        place of one Python call per query per leaf.
         Consolidated (interval-set) leaves are evaluated once per distinct
         interval set on the column, and their rows copied to every query
         that carries the same set (a GROUP BY's leaves share the brush).
-        Bit-for-bit equal to stacking ``_pair_betas`` per query (same
-        elementwise arithmetic, broadcast over a leading batch axis).
+        Bit-for-bit equal to evaluating each query's leaves one at a time
+        (same elementwise arithmetic, broadcast over a leading batch axis).
         ``memo`` (a ``_SliceMemo``) tallies the slices evaluated.
         """
         nq = len(leaf_lists)
@@ -268,39 +253,6 @@ class FastPath:
         if memo is not None:
             memo.computed += computed
         return betas
-
-    # ------------------------------------------------------------ single query
-
-    def __call__(self, ph, agg_col, tree, corrected):
-        split = self._split_leaves(ph, agg_col, tree)
-        if split is None:
-            return None  # OR / nested: NumPy reference path
-        same_col, pair_leaves = split
-        hist = ph.hists[agg_col]
-        h = np.asarray(hist.h, np.float64)
-
-        outs = []
-        if pair_leaves:
-            pred_cols = tuple(lf.col for lf in pair_leaves)
-            hpad, fpad, hxpad, k1c, k2max = self._get_stack(
-                ph, agg_col, pred_cols)
-            betas = self._pair_betas(ph, agg_col, pair_leaves, k2max)
-            for idx in range(3):
-                prob1 = np.asarray(fused_weightings(
-                    hpad, betas[idx], fpad, hxpad,
-                    use_pallas=self.use_pallas))[:k1c]
-                w = h * prob1
-                for prob in same_col[idx]:
-                    w = w * prob
-                outs.append(np.asarray(w, np.float64))
-        else:
-            for idx in range(3):
-                w = h.copy()
-                for prob in same_col[idx]:
-                    w = w * prob
-                outs.append(w)
-        w, wlo, whi = outs
-        return _widen_clip(w, wlo, whi, ph, h, corrected)
 
     # ------------------------------------------------------------- query batch
 
@@ -401,7 +353,3 @@ class FastPath:
             tracer.add("widen", t2, time.perf_counter(), track="worker")
         return out
 
-
-def make_fastpath(use_pallas: bool = True) -> FastPath:
-    """Returns the engine hook (kept for back-compat; now a FastPath)."""
-    return FastPath(use_pallas=use_pallas)

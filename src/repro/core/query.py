@@ -455,12 +455,9 @@ class QueryEngine:
     """Executes the paper's query templates against a PairwiseHist synopsis."""
 
     def __init__(self, ph: PairwiseHist,
-                 corrected_sampling_bounds: bool = False,
-                 fastpath=None):
+                 corrected_sampling_bounds: bool = False):
         self.ph = ph
         self.corrected = corrected_sampling_bounds
-        # Optional fused JAX/Pallas weightings path (repro.core.fastpath).
-        self.fastpath = fastpath
 
     # ------------------------------------------------------------------ API
 
@@ -667,10 +664,6 @@ class QueryEngine:
         return x
 
     def _weightings(self, agg_col, tree):
-        if self.fastpath is not None and tree is not None:
-            out = self.fastpath(self.ph, agg_col, tree, self.corrected)
-            if out is not None:
-                return out
         return wlib.weightings(self.ph, agg_col, tree,
                                corrected_sampling_bounds=self.corrected)
 

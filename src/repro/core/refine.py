@@ -24,22 +24,18 @@ the batched hist2d kernel (``repro.kernels.hist2d.batched_hist2d``) and the
 chi-squared sub-bin counts through the batched sub-bin kernel
 (``repro.kernels.subbin`` via ``chi2.subbin_counts``) — Pallas one-hot
 matmuls on TPU, dtype-preserving scatter/segment-sum oracles elsewhere.
-Two schedulers drive that round:
-
-  * ``refine_2d_batch`` — fixed chunk: ONE ``lax.while_loop`` runs until
-    the slowest pair converges (converged pairs are at a fixed point —
-    recomputing them yields no new splits);
-  * ``refine_2d_compact`` — convergence-compacting: a fixed set of slots
-    refines an arbitrarily long pending queue, draining each pair the
-    round it converges and backfilling its slot, so deep-refining pairs
-    never stall shallow ones (full occupancy until the queue runs dry).
+``refine_2d_compact`` drives that round, convergence-compacting: a fixed
+set of slots refines an arbitrarily long pending queue, draining each pair
+the round it converges and backfilling its slot, so deep-refining pairs
+never stall shallow ones (full occupancy until the queue runs dry).
 
 Each pair is presorted once by (x, y) and (y, x) (``presort_pairs``), which
-turns the former per-round ``lexsort`` in ``_slice_unique`` into cheap
-run-boundary flag sums — counts are exact integers, so both batched
-schedulers are bit-for-bit equal to the legacy per-pair ``refine_2d`` loop
+turns the per-round ``lexsort`` in ``_slice_unique`` into cheap
+run-boundary flag sums — counts are exact integers, so the compacting
+scheduler is bit-for-bit equal to the per-pair ``refine_2d`` loop
 (asserted in tests). ``refine_2d``/``pair_metadata`` remain as the
-single-pair reference path.
+single-pair reference path, the oracle ``build.build_pairs_sequential``
+runs.
 """
 from __future__ import annotations
 
@@ -402,9 +398,9 @@ def presort_pairs(x, y, valid):
     predecessor. Summing those flags per cell gives the exact distinct-x
     count per cell with no per-round sort (ditto distinct-y via order 2).
 
-    ``build.build_pairs_batched`` computes the same arrays host-side with
-    ``np.lexsort`` (numpy's sort is much faster than XLA:CPU's); this jitted
-    version serves device-resident callers and tests.
+    ``build._presort_pairs_host`` computes the same arrays host-side
+    (numpy's sort is much faster than XLA:CPU's); this jitted version is
+    the device counterpart it is tested against.
     """
     key_x = jnp.where(valid, x, _INF)
     key_y = jnp.where(valid, y, _INF)
@@ -456,8 +452,8 @@ def _round_2d_batch(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
                     interpret: bool | None):
     """ONE level-synchronous refinement round over P pairs.
 
-    The shared inner step of ``refine_2d_batch`` (fixed chunk) and
-    ``refine_2d_compact`` (drain/backfill active set): per-cell statistics
+    The inner step of ``refine_2d_compact`` (drain/backfill active set),
+    over its slots: per-cell statistics
     via the batched hist2d + sub-bin kernels, split selection, capacity
     guard, edge insertion. Returns (ex, ey, kx, ky, n_split, capped_round)
     with per-pair split and guard-bound flags for this round. Exactly the
@@ -532,50 +528,6 @@ def _round_2d_batch(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
             (nx + ny).astype(jnp.int32), capped_round)
 
 
-@functools.partial(jax.jit, static_argnames=("k2", "s_max", "max_rounds",
-                                             "use_pallas", "interpret"))
-def refine_2d_batch(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
-                    ex0, ey0, kx0, ky0, min_points, crit_table, *,
-                    k2: int, s_max: int = 32, max_rounds: int = 16,
-                    use_pallas: bool = False, interpret: bool | None = None):
-    """Refine P pair histograms in one level-synchronous while_loop.
-
-    Inputs are ``presort_pairs`` outputs plus per-pair initial edges
-    ``ex0``/``ey0`` (P, K2+1) and valid-bin counts ``kx0``/``ky0`` (P,).
-    Returns (ex, ey, kx, ky, capped) with leading pair axis.
-
-    Per-pair results are bit-for-bit identical to running ``refine_2d`` on
-    each pair alone: a pair that stops splitting is at a deterministic fixed
-    point, so the extra rounds it sits through while slower pairs converge
-    are no-ops, and every per-cell statistic is an exact integer count or a
-    float computed by the same ops on the same values.
-
-    ``capped[p]`` is True iff pair p's K2-capacity guard ever dropped a
-    wanted split. When False, the result is independent of ``k2`` (any
-    capacity >= the final bin counts yields the same histogram), which is
-    what lets construction refine at a small capacity first and escalate
-    only saturated chunks (``build.build_pairs_batched``).
-    """
-    p = xo1.shape[0]
-
-    def cond(state):
-        _, _, _, _, n_split, _, rounds = state
-        return jnp.any(n_split > 0) & (rounds < max_rounds)
-
-    def body(state):
-        ex, ey, kx, ky, _, capped, rounds = state
-        ex, ey, kx, ky, n_split, capped_r = _round_2d_batch(
-            xo1, yo1, vo1, new1, xo2, yo2, vo2, new2, ex, ey, kx, ky,
-            min_points, crit_table, k2=k2, s_max=s_max,
-            use_pallas=use_pallas, interpret=interpret)
-        return ex, ey, kx, ky, n_split, capped | capped_r, rounds + 1
-
-    state = (ex0, ey0, kx0.astype(jnp.int32), ky0.astype(jnp.int32),
-             jnp.ones(p, jnp.int32), jnp.zeros(p, bool), jnp.int32(0))
-    ex, ey, kx, ky, _, capped, _ = jax.lax.while_loop(cond, body, state)
-    return ex, ey, kx, ky, capped
-
-
 @functools.partial(jax.jit, static_argnames=("n_slots", "k2", "s_max",
                                              "max_rounds", "drain_capped",
                                              "use_pallas", "interpret"))
@@ -588,14 +540,15 @@ def refine_2d_compact(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
                       interpret: bool | None = None):
     """Convergence-compacting refinement: an S-slot active set over P pairs.
 
-    The fixed-chunk ``refine_2d_batch`` runs until the *slowest* pair of
-    its chunk converges — deep-refining (correlated) pairs lockstep-drag
-    shallow ones. Here ``n_slots`` device-side slots refine one round per
-    loop iteration; every iteration, slots whose pair converged this round
-    (no splits, or ``max_rounds`` reached, or — when ``drain_capped`` —
-    the capacity guard bound) **drain** into per-pair output buffers and
-    **backfill** from the pending queue ``[next_ptr, n_pending)``, so the
-    active set stays at full occupancy until the queue runs dry.
+    A single ``lax.while_loop`` over a fixed chunk would run until the
+    *slowest* pair of the chunk converges — deep-refining (correlated)
+    pairs would lockstep-drag shallow ones. Here ``n_slots`` device-side
+    slots refine one round per loop iteration; every iteration, slots
+    whose pair converged this round (no splits, or ``max_rounds`` reached,
+    or — when ``drain_capped`` — the capacity guard bound) **drain** into
+    per-pair output buffers and **backfill** from the pending queue
+    ``[next_ptr, n_pending)``, so the active set stays at full occupancy
+    until the queue runs dry.
 
     Inputs are the presorted arrays of ALL P pending pairs plus per-pair
     start states (``ex0``/``ey0``/``kx0``/``ky0``/``rounds0``/``capped0``
@@ -613,6 +566,12 @@ def refine_2d_compact(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
     and the pair re-queued one rung up, so keeping it refining would only
     burn its slot. On the final rung it must be False (the capped result
     is the real, fully-refined K2-capped histogram).
+
+    ``out_capped[p]`` is True iff pair p's K2-capacity guard ever dropped
+    a wanted split. When False, the result is independent of ``k2`` (any
+    capacity >= the final bin counts yields the same histogram), which is
+    what lets construction refine at a small capacity rung first and
+    re-queue only the capped pairs one rung up.
 
     ``occupancy_min`` (traced, 0 disables): once the queue is empty and
     fewer than ``ceil(occupancy_min * n_slots)`` slots remain active, the
@@ -791,27 +750,3 @@ def pair_metadata_batch(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
     uy = jnp.where(empty_y, 0.0, uy)
     return H, hx, ux, vminx, vmaxx, hy, uy, vminy, vmaxy
 
-
-@functools.partial(jax.jit, static_argnames=("k2", "s_max", "max_rounds",
-                                             "use_pallas", "interpret"))
-def build_pairs_device(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
-                       ex0, ey0, kx0, ky0, min_points,
-                       crit_table, *, k2: int, s_max: int = 32,
-                       max_rounds: int = 16, use_pallas: bool = False,
-                       interpret: bool | None = None):
-    """Batched refine + batched metadata as ONE compiled unit.
-
-    Takes presorted chunk arrays (``presort_pairs`` layout — device- or
-    host-produced). Everything for a chunk of P pairs runs in a single
-    dispatch; the caller fetches all results in one grouped device->host
-    transfer. Returns
-    (ex, ey, kx, ky, capped, H, hx, ux, vminx, vmaxx, hy, uy, vminy, vmaxy).
-    """
-    pres = (xo1, yo1, vo1, new1, xo2, yo2, vo2, new2)
-    ex, ey, kx, ky, capped = refine_2d_batch(
-        *pres, ex0, ey0, kx0, ky0, min_points, crit_table, k2=k2,
-        s_max=s_max, max_rounds=max_rounds, use_pallas=use_pallas,
-        interpret=interpret)
-    meta = pair_metadata_batch(*pres, ex, ey, kx, ky, k2=k2,
-                               use_pallas=use_pallas, interpret=interpret)
-    return (ex, ey, kx, ky, capped) + meta

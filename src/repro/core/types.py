@@ -40,24 +40,20 @@ class BuildParams:
     max_rounds_1d: int = 64           # refinement rounds (== max recursion depth)
     max_rounds_2d: int = 16
     use_pallas: bool = False          # route 2-D binning through the Pallas kernel
-    # Pair-batched construction (the 2-D hot path). ``pair_chunk`` bounds how
-    # many pairs refine per launch (memory ~ pair_chunk * k2_cap^2 * s2_max);
-    # launch sizes bucket to powers of two (pair_chunk rounds DOWN so the
-    # memory bound is honoured) to bound jit recompiles.
-    pair_batched: bool = True         # batched 2-D path vs legacy per-pair loop
+    # Pair-batched construction (the 2-D hot path, build_pairs_compact):
+    # ``pair_chunk`` slots refine a device-resident pending queue, draining
+    # each pair the round it converges and backfilling its slot, so deep
+    # (correlated) pairs never lockstep-drag shallow ones. ``pair_chunk``
+    # bounds how many pairs refine per launch (memory ~ pair_chunk *
+    # k2_cap^2 * s2_max); launch sizes bucket to powers of two (pair_chunk
+    # rounds DOWN so the memory bound is honoured) to bound jit recompiles.
     pair_chunk: int = 8               # max pairs per batched launch (pow-2)
-    # Adaptive 2-D capacity: chunks refine at the smallest rung of the
+    # Adaptive 2-D capacity: pairs refine at the smallest rung of the
     # doubling ladder k2_start, 2*k2_start, ..., k2_cap that fits their
     # initial grids, escalating only when the capacity guard binds (the
     # result is capacity-independent otherwise). Real pair grids are tens of
     # bins, so the k2_cap^2 * s2_max chi-squared workspace shrinks ~16x.
     k2_start: int = 64                # first rung of the capacity ladder
-    # Convergence-compacting refinement (build_pairs_compact): pair_chunk
-    # slots refine a device-resident pending queue, draining each pair the
-    # round it converges and backfilling its slot, so deep (correlated)
-    # pairs never lockstep-drag shallow ones. False falls back to the
-    # fixed-chunk scheduler (the PR 2 path, kept as baseline/escape hatch).
-    compact_drain: bool = True        # drain/backfill vs fixed-chunk lockstep
     # Early-exit threshold for a compacted launch's tail: once the pending
     # queue is empty and fewer than ceil(occupancy_min * slots) slots are
     # still active, the launch returns and the unconverged pairs re-bucket

@@ -1,13 +1,14 @@
-"""Pallas-TPU kernel: 2-D histogram via one-hot matmuls on the MXU.
+"""Pallas-TPU kernel: pair-batched 2-D histograms via one-hot matmuls on
+the MXU.
 
 Scatter-adds serialize on TPU; instead each grid step turns a tile of TN
-rows into two one-hot matrices and accumulates
+rows of one pair into two one-hot matrices and accumulates
 
-    H += one_hot(bi_tile)^T  @  (one_hot(bj_tile) * w_tile)
+    H_p += one_hot(bi_tile) @ (one_hot(bj_tile) * w_tile)^T
 
-— a (KI x TN) @ (TN x KJ) systolic matmul. The full (KI, KJ) accumulator
-lives in VMEM across grid steps (KI, KJ <= 512 -> <= 1 MiB f32); row tiles
-stream HBM -> VMEM via BlockSpec.
+— a (KI x TN) @ (TN x KJ) systolic matmul. Each pair's (KI, KJ)
+accumulator lives in VMEM across its row tiles (KI, KJ <= 512 -> <= 1 MiB
+f32); row tiles stream HBM -> VMEM via BlockSpec.
 
 This is the TPU adaptation of PairwiseHist construction's hot spot (DESIGN.md
 §3): bin counting for d(d-1)/2 pair histograms over N_s sampled rows.
@@ -24,44 +25,6 @@ from jax.experimental import pallas as pl
 # Block indices must be int32: under ``jax_enable_x64`` a bare ``0`` in an
 # index map traces as int64, and Mosaic then fails to lower the kernel.
 _I0 = np.int32(0)
-
-
-def _kernel(bi_ref, bj_ref, w_ref, out_ref, *, ki: int, kj: int, tn: int):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    bi = bi_ref[...]                                   # (TN,) i32
-    bj = bj_ref[...]
-    w = w_ref[...].astype(jnp.float32)                 # (TN,)
-    rows_i = jax.lax.broadcasted_iota(jnp.int32, (tn, ki), 1)
-    rows_j = jax.lax.broadcasted_iota(jnp.int32, (tn, kj), 1)
-    oh_i = (rows_i == bi[:, None]).astype(jnp.float32)             # (TN, KI)
-    oh_j = (rows_j == bj[:, None]).astype(jnp.float32) * w[:, None]
-    out_ref[...] += jax.lax.dot_general(
-        oh_i, oh_j, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (KI, KJ)
-
-
-@functools.partial(jax.jit, static_argnames=("ki", "kj", "tn", "interpret"))
-def hist2d_pallas(bi, bj, weights, ki: int, kj: int, tn: int = 1024,
-                  interpret: bool = True):
-    """bi/bj: (N,) int32 (N % tn == 0; pad with weight-0 rows), w: (N,)."""
-    n = bi.shape[0]
-    assert n % tn == 0, "pad N to a multiple of the row tile in ops.py"
-    grid = (n // tn,)
-    return pl.pallas_call(
-        functools.partial(_kernel, ki=ki, kj=kj, tn=tn),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tn,), lambda i: (i,)),
-            pl.BlockSpec((tn,), lambda i: (i,)),
-            pl.BlockSpec((tn,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((ki, kj), lambda i: (_I0, _I0)),
-        out_shape=jax.ShapeDtypeStruct((ki, kj), jnp.float32),
-        interpret=interpret,
-    )(bi, bj, weights)
 
 
 def _batched_kernel(bi_ref, bj_ref, w_ref, out_ref, *, ki: int, kj: int,

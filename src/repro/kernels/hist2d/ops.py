@@ -5,41 +5,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.hist2d.hist2d import batched_hist2d_pallas, hist2d_pallas
-from repro.kernels.hist2d.ref import batched_hist2d_ref, hist2d_ref
+from repro.kernels.hist2d.hist2d import batched_hist2d_pallas
+from repro.kernels.hist2d.ref import batched_hist2d_ref
 
 
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
-
-
-def hist2d(bi, bj, weights, ki: int, kj: int, *, use_pallas: bool = True,
-           interpret: bool | None = None, tn: int = 1024):
-    """Weighted 2-D histogram (KI, KJ) from per-point bin indices.
-
-    On TPU the Pallas kernel runs compiled; on CPU it runs in interpret mode
-    (the kernel body executed in Python — correctness path). K dims are
-    padded to multiples of 128 (MXU lanes), N to the row tile.
-    """
-    bi = jnp.asarray(bi, jnp.int32)
-    bj = jnp.asarray(bj, jnp.int32)
-    weights = jnp.asarray(weights, jnp.float32)
-    if not use_pallas:
-        return hist2d_ref(bi, bj, weights, ki, kj)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    n = bi.shape[0]
-    n_pad = _round_up(max(n, tn), tn)
-    ki_pad = _round_up(ki, 128)
-    kj_pad = _round_up(kj, 128)
-    if n_pad != n:
-        pad = n_pad - n
-        bi = jnp.pad(bi, (0, pad))
-        bj = jnp.pad(bj, (0, pad))
-        weights = jnp.pad(weights, (0, pad))  # zero weight => no contribution
-    out = hist2d_pallas(bi, bj, weights, ki_pad, kj_pad, tn=tn,
-                        interpret=bool(interpret))
-    return out[:ki, :kj]
 
 
 def batched_hist2d(bi, bj, weights, ki: int, kj: int, *,
@@ -84,35 +55,3 @@ def batched_hist2d(bi, bj, weights, ki: int, kj: int, *,
                                 ki_pad, kj_pad, tn=tn,
                                 interpret=bool(interpret))
     return out[:, :ki, :kj].astype(weights.dtype)
-
-
-def hist2d_sharded(bi, bj, weights, ki: int, kj: int, mesh,
-                   axis: str = "data", use_pallas: bool | None = None):
-    """Row-sharded distributed bin counting (DESIGN.md §3.5).
-
-    Rows shard across the mesh's ``axis``; each device bins its shard and
-    the (ki, kj) count matrix reduces via the psum GSPMD inserts for the
-    replicated output. This is the pod-scale construction path: refinement
-    decisions depend only on these counts, so only counts ever cross chips.
-
-    Binning routes through ``batched_hist2d`` (as a P=1 batch), the same
-    dispatch the pair-batched construction loop uses — one kernel to
-    validate and tune for both scales. ``use_pallas=None`` resolves by
-    backend (Pallas on TPU, jnp oracle elsewhere — off-TPU interpret mode
-    is a correctness path, not a speed path, and the oracle needs no row
-    padding, which under GSPMD would force a reshard).
-    """
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    row_sharding = NamedSharding(mesh, P(axis))
-    rep = NamedSharding(mesh, P())
-    bi = jax.device_put(jnp.asarray(bi, jnp.int32), row_sharding)
-    bj = jax.device_put(jnp.asarray(bj, jnp.int32), row_sharding)
-    weights = jax.device_put(jnp.asarray(weights, jnp.float32), row_sharding)
-    fn = jax.jit(lambda a, b, w: batched_hist2d(
-        a[None], b[None], w[None], ki, kj, use_pallas=use_pallas)[0],
-        out_shardings=rep)
-    return fn(bi, bj, weights)

@@ -1,29 +1,19 @@
-"""Pure-jnp oracles for the hist2d kernels."""
+"""Pure-jnp oracle for the pair-batched hist2d kernel."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
 
-def hist2d_ref(bi, bj, weights, ki: int, kj: int):
-    """Weighted 2-D histogram: H[a, b] = sum_n w_n [bi_n == a][bj_n == b].
-
-    bi/bj: (N,) int32 bin indices (out-of-range rows must carry weight 0).
-    weights: (N,) float32.
-    """
-    h = jnp.zeros((ki, kj), jnp.float32)
-    bi = jnp.clip(bi, 0, ki - 1)
-    bj = jnp.clip(bj, 0, kj - 1)
-    return h.at[bi, bj].add(weights.astype(jnp.float32))
-
-
 def batched_hist2d_ref(bi, bj, weights, ki: int, kj: int):
-    """Pair-batched oracle: (P, N) indices/weights -> (P, KI, KJ).
+    """Pair-batched oracle: (P, N) indices/weights -> (P, KI, KJ) with
+    H[p, a, b] = sum_n w[p, n] [bi[p, n] == a][bj[p, n] == b]
+    (out-of-range rows must carry weight 0).
 
-    Unlike ``hist2d_ref`` this *preserves the weight dtype*: synopsis
-    construction feeds f64 ones/flags and compares counts bit-for-bit
-    against the sequential per-pair ``segment_sum`` path (counts are exact
-    integers, so the f32 Pallas path agrees too for N < 2^24).
+    It *preserves the weight dtype*: synopsis construction feeds f64
+    ones/flags and compares counts bit-for-bit against the sequential
+    per-pair ``segment_sum`` path (counts are exact integers, so the f32
+    Pallas path agrees too for N < 2^24).
     """
     def one(bi_p, bj_p, w_p):
         h = jnp.zeros((ki, kj), weights.dtype)

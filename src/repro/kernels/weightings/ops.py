@@ -1,15 +1,13 @@
-"""Host wrappers for the fused weightings kernels: pad, dispatch and,
-for the query-batched launch, the copy back."""
+"""Host wrapper for the query-batched fused weightings kernel: pad,
+dispatch and the copy back."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.weightings.ref import (batched_weightings_ref,
-                                          fused_weightings_ref)
-from repro.kernels.weightings.weightings import (batched_weightings_pallas,
-                                                 fused_weightings_pallas)
+from repro.kernels.weightings.ref import batched_weightings_ref
+from repro.kernels.weightings.weightings import batched_weightings_pallas
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -34,38 +32,7 @@ def q_bucket(q: int) -> int:
     return max(8, 1 << (int(q) - 1).bit_length())
 
 
-_ref_jit = jax.jit(fused_weightings_ref)
 _batched_ref_jit = jax.jit(batched_weightings_ref)
-
-
-def fused_weightings(h_stack, beta, fold, hx, *, use_pallas: bool = True,
-                     interpret: bool | None = None):
-    """See ref.py for semantics. Pads K1/K2 to 128 multiples for the MXU.
-
-    Padding is value-safe: padded H rows/cols and beta/hx entries are zero
-    => p_row pads to 0; padded fold rows are zero => p1 pads to 0 and those
-    1-D bins are sliced away.
-    """
-    h_stack = jnp.asarray(h_stack, jnp.float32)
-    beta = jnp.asarray(beta, jnp.float32)
-    fold = jnp.asarray(fold, jnp.float32)
-    hx = jnp.asarray(hx, jnp.float32)
-    if not use_pallas:
-        return _ref_jit(h_stack, beta, fold, hx)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    el, k2, _ = h_stack.shape
-    k1 = fold.shape[1]
-    k2p = _round_up(k2, 128)
-    k1p = _round_up(k1, 128)
-    if (k2p, k1p) != (k2, k1):
-        h_stack = jnp.pad(h_stack, ((0, 0), (0, k2p - k2), (0, k2p - k2)))
-        beta = jnp.pad(beta, ((0, 0), (0, k2p - k2)))
-        hx = jnp.pad(hx, ((0, 0), (0, k2p - k2)))
-        fold = jnp.pad(fold, ((0, 0), (0, k1p - k1), (0, k2p - k2)))
-    out = fused_weightings_pallas(h_stack, beta, fold, hx,
-                                  interpret=bool(interpret))
-    return out[:k1]
 
 
 def _f32(x, shape=None):

@@ -1,23 +1,7 @@
-"""Pure-jnp oracle for the fused weightings kernel."""
+"""Pure-jnp oracle for the query-batched fused weightings kernel."""
 from __future__ import annotations
 
 import jax.numpy as jnp
-
-
-def fused_weightings_ref(h_stack, beta, fold, hx):
-    """prod_l fold_l( clip( (H_l @ beta_l) / hx_l , 0, 1) )  — Eq. 25/27/28.
-
-    h_stack: (L, K2, K2)  padded pair-count matrices (x-dim = agg column)
-    beta:    (L, K2)      coverage vectors on the predicate columns' slices
-    fold:    (L, K1, K2)  one-hot gather: 1-D bin -> containing pair x-row
-    hx:      (L, K2)      pair x-row totals
-    Returns  (K1,) per-1-D-bin probability product; the caller multiplies by
-    the 1-D bin counts h^(i) to obtain weightings (Eq. 24).
-    """
-    v = jnp.einsum("lab,lb->la", h_stack, beta)          # (L, K2)
-    p_row = jnp.clip(v / jnp.maximum(hx, 1e-30), 0.0, 1.0)
-    p1 = jnp.einsum("lka,la->lk", fold, p_row)           # (L, K1)
-    return jnp.prod(p1, axis=0)
 
 
 def batched_weightings_ref(h_stack, beta, fold, hx):

@@ -70,7 +70,7 @@ class ColdTable:
     BACKOFF_CAP_S = 1.0
 
     def __init__(self, blob: bytes, compressed=None,
-                 params: BuildParams | None = None, fastpath=None,
+                 params: BuildParams | None = None,
                  decode_cb=None, decode_retries: int = 2,
                  decode_backoff_s: float = 0.01,
                  breaker_reset_s: float = 0.0, fault_cb=None):
@@ -78,7 +78,6 @@ class ColdTable:
         self.blob = bytes(blob)
         self.compressed = compressed
         self.params = params
-        self.fastpath = fastpath
         self.decode_cb = decode_cb
         # Resilience policy: a failed decode is retried decode_retries
         # times with capped exponential backoff (decode_backoff_s base);
@@ -213,7 +212,7 @@ class ColdTable:
                     f"attempts (re-register or reset_faults() to recover): "
                     f"{last!r}") from last
             self._fault = None
-            engine = QueryEngine(ph, fastpath=self.fastpath)
+            engine = QueryEngine(ph)
             decode_s = time.perf_counter() - t0
             self.decode_count += 1
             self._engine_nbytes = ph.nbytes
@@ -291,7 +290,7 @@ class ColdTable:
             t0 = time.perf_counter()
             ph = build_pairwise_hist(self.compressed, columns, build_params)
             blob = storagemod.encode(ph)
-            engine = QueryEngine(ph, fastpath=self.fastpath)
+            engine = QueryEngine(ph)
             build_s = time.perf_counter() - t0
             with self._lock:
                 if self._published[1] > epoch_new:
@@ -349,16 +348,14 @@ class TableCatalog:
 
     def register_table(self, name: str, table: dict,
                        params: BuildParams | None = None,
-                       use_compression: bool = True,
-                       fastpath=None) -> AQPFramework:
+                       use_compression: bool = True) -> AQPFramework:
         """Convenience: build + ingest a framework from a raw column dict."""
-        fw = AQPFramework(params=params, use_compression=use_compression,
-                          fastpath=fastpath)
+        fw = AQPFramework(params=params, use_compression=use_compression)
         fw.ingest(table)
         return self.register(name, fw)
 
     def register_cold(self, name: str, blob: bytes, compressed=None,
-                      params: BuildParams | None = None, fastpath=None,
+                      params: BuildParams | None = None,
                       decode_cb=None, decode_retries: int = 2,
                       decode_backoff_s: float = 0.01,
                       breaker_reset_s: float = 0.0,
@@ -369,7 +366,7 @@ class TableCatalog:
         backoff / breaker knobs and ``fault_cb`` configure decode
         resilience (see ``docs/robustness.md``)."""
         cold = ColdTable(blob, compressed=compressed, params=params,
-                         fastpath=fastpath, decode_cb=decode_cb,
+                         decode_cb=decode_cb,
                          decode_retries=decode_retries,
                          decode_backoff_s=decode_backoff_s,
                          breaker_reset_s=breaker_reset_s, fault_cb=fault_cb)

@@ -3,8 +3,7 @@
 Production code calls :func:`hook` at named *sites* (cold decode, blob
 read, fused kernel launch, planner, wave execute, worker).  With no plan
 installed the hook is a single global read plus an ``is None`` branch —
-cheap enough to leave in the hot path permanently (the disabled cost is
-measured by ``benchmarks/bench_serving.py`` and gated below 2% of p50).
+cheap enough to leave in the hot path permanently.
 
 Chaos tests install a seeded :class:`FaultPlan` that scripts *exact*
 failure schedules: "fail the 3rd cold decode", "crash the worker on its

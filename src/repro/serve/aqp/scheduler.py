@@ -31,8 +31,8 @@ Execution modes:
   * ``None``     — auto: "pallas" on TPU, "numpy" elsewhere. On CPU the
                    per-launch JAX dispatch the fused kernel amortizes on
                    TPU *is* the overhead, so fusing small groups loses to
-                   NumPy (same reasoning as bench_kernels.py: Pallas off-TPU
-                   is for correctness, not speed).
+                   NumPy (Pallas off-TPU interprets the kernel: it is for
+                   correctness, not speed).
 """
 from __future__ import annotations
 

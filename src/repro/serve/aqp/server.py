@@ -35,9 +35,8 @@ a full queue resolves the overflowing submission's futures with a typed
 ``AdmissionRejected`` *result* (never an exception raised in the worker)
 according to ``shed_policy`` — see ``scheduler.StreamingAdmission``.
 
-**Locking** (lock-split submit path): two locks replace the original
-single server RLock so concurrent submitters no longer serialize against
-each other or against wave resolution:
+**Locking**: two locks, so concurrent submitters do not serialize
+against each other or against wave resolution:
 
   * ``_plan_lock`` — read-mostly: guards the plan cache only. Planning
     itself (parse + literal encoding + GROUP BY leaf expansion, the
@@ -49,9 +48,7 @@ each other or against wave resolution:
     callbacks never run under (or deadlock against) a server lock.
 
 The only nesting is ``_state_lock`` -> ``_plan_lock`` (re-plan inside a
-wave); nothing acquires them in the reverse order. ``single_lock=True``
-collapses both to one lock and plans inside it — the pre-split critical
-section, kept as the contention baseline for ``benchmarks/bench_serving``.
+wave); nothing acquires them in the reverse order.
 
 GROUP BY queries ride the batched fast path: plans arrive from
 ``core/query.py`` already expanded into per-category leaf plans, the server
@@ -173,9 +170,6 @@ class AQPServer:
             futures resolve with ``AdmissionRejected``.
         retry_timeout_s: ``query_batch``'s drain-and-retry budget when its
             submissions are rejected by the bounded queue.
-        single_lock: compatibility/benchmark baseline — plan under the one
-            big server lock (the pre-split critical section) instead of the
-            lock-split submit path.
         trace_enabled: per-query tracing (``repro.obs``): every submission
             carries a ``QueryTrace`` through submit -> admission -> wave ->
             resolution, its result gains an ``explain`` stage breakdown,
@@ -224,7 +218,7 @@ class AQPServer:
                  max_group: int = 256, min_group: int = 2,
                  max_wait_ms: float = 2.0, max_batch: int = 64,
                  max_queue_depth: int = 1024, shed_policy: str = "reject",
-                 retry_timeout_s: float = 30.0, single_lock: bool = False,
+                 retry_timeout_s: float = 30.0,
                  trace_enabled: bool = False, trace_buffer: int = 65536,
                  slow_query_ms: float = 100.0,
                  max_engine_bytes: int = 0, demote_idle_s: float = 0.0):
@@ -262,15 +256,12 @@ class AQPServer:
         self._planner = (PlannerPool(planner_workers)
                          if planner_workers > 0 else None)
         self.retry_timeout_s = float(retry_timeout_s)
-        self.single_lock = bool(single_lock)
         self._wiring: dict[str, tuple] = {}   # name -> (framework, callback)
         # Lock split (see module docstring): _state_lock guards result
         # cache + metrics + in-flight map; _plan_lock guards the plan cache.
-        # Both RLocks: invalidation callbacks and the single_lock baseline
-        # re-enter them. single_lock collapses the two into one.
+        # Both RLocks: invalidation callbacks re-enter them.
         self._state_lock = threading.RLock()
-        self._plan_lock = (self._state_lock if single_lock
-                           else threading.RLock())
+        self._plan_lock = threading.RLock()
         self._inflight: dict[str, _Submission] = {}
         # norm -> (table, cause): statements refused after repeated
         # execution failure. Guarded by _state_lock; bounded; cleared by
@@ -296,7 +287,7 @@ class AQPServer:
         return self
 
     def register_cold(self, name: str, blob: bytes, compressed=None,
-                      params=None, fastpath=None, decode_retries: int = 2,
+                      params=None, decode_retries: int = 2,
                       decode_backoff_s: float = 0.01,
                       breaker_reset_s: float = 0.0) -> "AQPServer":
         """Register a cold (storage-tier) table: a bit-packed synopsis blob
@@ -314,7 +305,6 @@ class AQPServer:
         ring's "faults" lane."""
         cold = self.catalog.register_cold(
             name, blob, compressed=compressed, params=params,
-            fastpath=fastpath,
             decode_cb=lambda n, s, name=name: self._on_cold_decode(name, n, s),
             decode_retries=decode_retries, decode_backoff_s=decode_backoff_s,
             breaker_reset_s=breaker_reset_s,
@@ -488,9 +478,9 @@ class AQPServer:
         repeated execution failures resolves immediately with a typed
         ``QueryError`` (``kind="quarantined"``).
 
-        On the lock-split path the expensive planning step runs with no
-        server lock held; only the dedupe check / admission bookkeeping
-        take the short state lock.
+        Planning, the expensive step, runs with no server lock held; only
+        the dedupe check and admission bookkeeping take the short state
+        lock.
         """
         fut = QueryFuture(sql_text)
         t_submit = time.perf_counter()
@@ -500,7 +490,6 @@ class AQPServer:
         # Per-query trace only when tracing: the disabled path pays no
         # allocation beyond the future itself.
         trace = QueryTrace(t_submit) if self.tracer.enabled else None
-        sub = None
         with self._state_lock:
             self.metrics.admission.record_submit()
             quarantined = self._quarantine.get(norm)
@@ -512,16 +501,12 @@ class AQPServer:
                 if inflight is not None:      # identical query already queued
                     inflight.futures.append(fut)
                     return fut
-                if self.single_lock:          # legacy: plan under the lock
-                    sub = self._plan_admit(fut, norm, t_submit, trace,
-                                           deadline_at)
         if quarantined is not None:
             fut.set_result(QueryError(
                 error=quarantined[1], kind="quarantined",
                 retries=self.MAX_EXEC_FAILURES))
             return fut
-        if not self.single_lock:
-            sub = self._plan_admit(fut, norm, t_submit, trace, deadline_at)
+        sub = self._plan_admit(fut, norm, t_submit, trace, deadline_at)
         if sub is not None:
             self._enqueue(sub)
         return fut

@@ -1,19 +1,23 @@
-"""Pair-batched 2-D construction vs the legacy sequential per-pair loop.
+"""Pair-batched 2-D construction vs the sequential per-pair oracle, on a
+table with a constant column and a NULL-heavy column.
 
-The batched path (refine.refine_2d_batch / pair_metadata_batch driven by
-build.build_pairs_batched) must be *bit-for-bit* equal to the legacy host
-loop (build.build_pairs_sequential) in oracle (numpy/jnp) mode: every count
-is an exact integer and every float statistic is computed by the same ops on
-the same values. Covers NaN-masked rows, constant columns, the K2-capacity
-guard, chunk bucketing, and the adaptive capacity ladder.
+The served pair phase (build.build_pairs_compact: refine.refine_2d_compact
+and pair_metadata_batch) must be *bit-for-bit* equal to the per-pair host
+loop (build.build_pairs_sequential, built by conftest._sequential through
+the module-private build._build_pairwise_hist) in oracle (numpy/jnp) mode:
+every count is an exact integer and every float statistic is computed by
+the same ops on the same values. Covers NaN-masked rows, constant columns,
+the K2-capacity guard, slot-count bucketing, and the adaptive capacity
+ladder.
 """
 import dataclasses
 
 import numpy as np
 import pytest
 
+from conftest import _assert_same_synopsis, _cols, _sequential
 from repro.core.build import build_pairwise_hist
-from repro.core.types import BuildParams, ColumnInfo
+from repro.core.types import BuildParams
 
 
 def _table(n=6000, seed=7):
@@ -27,22 +31,6 @@ def _table(n=6000, seed=7):
     return np.stack([c0, c1, c2, c3, c4], 1)
 
 
-def _cols(d):
-    return [ColumnInfo(name=f"c{i}", kind="int") for i in range(d)]
-
-
-def _assert_same_synopsis(a, b):
-    for h1, h2 in zip(a.hists, b.hists):
-        for f, x, y in zip(h1._fields, h1, h2):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
-                                          err_msg=f"hist field {f}")
-    assert set(a.pairs) == set(b.pairs)
-    for key in a.pairs:
-        for f, x, y in zip(a.pairs[key]._fields, a.pairs[key], b.pairs[key]):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
-                                          err_msg=f"pair {key} field {f}")
-
-
 @pytest.fixture(scope="module")
 def data():
     return _table()
@@ -50,36 +38,33 @@ def data():
 
 @pytest.fixture(scope="module")
 def seq_synopsis(data):
-    params = BuildParams(n_samples=data.shape[0], k2_cap=64, s2_max=16,
-                         pair_batched=False)
-    return build_pairwise_hist(data, _cols(data.shape[1]), params)
+    params = BuildParams(n_samples=data.shape[0], k2_cap=64, s2_max=16)
+    return _sequential(data, _cols(data.shape[1]), params)
 
 
 def test_batched_equals_sequential_bitforbit(data, seq_synopsis):
     params = BuildParams(n_samples=data.shape[0], k2_cap=64, s2_max=16,
-                         pair_batched=True, pair_chunk=4)
+                         pair_chunk=4)
     batched = build_pairwise_hist(data, _cols(data.shape[1]), params)
     _assert_same_synopsis(seq_synopsis, batched)
 
 
 def test_chunk_bucketing_invariance(data, seq_synopsis):
-    """Chunk size (incl. non-pow2 -> padded dummy lanes) never changes bits."""
+    """Slot count (incl. non-pow2, rounded down) never changes bits."""
     for chunk in (1, 2, 3, 16):
         params = BuildParams(n_samples=data.shape[0], k2_cap=64, s2_max=16,
-                             pair_batched=True, pair_chunk=chunk)
+                             pair_chunk=chunk)
         batched = build_pairwise_hist(data, _cols(data.shape[1]), params)
         _assert_same_synopsis(seq_synopsis, batched)
 
 
 def test_capacity_ladder_escalation(data):
-    """A tiny first rung forces the guard to bind and the chunk to re-run
-    one rung up; the escalated result must still match the legacy loop run
-    directly at full capacity."""
-    p_seq = BuildParams(n_samples=data.shape[0], k2_cap=128, s2_max=16,
-                        pair_batched=False)
-    p_esc = dataclasses.replace(p_seq, pair_batched=True, pair_chunk=4,
-                                k2_start=4)
-    seq = build_pairwise_hist(data, _cols(data.shape[1]), p_seq)
+    """A tiny first rung forces the guard to bind and the capped pairs to
+    re-run one rung up; the escalated result must still match the
+    sequential loop run directly at full capacity."""
+    p_seq = BuildParams(n_samples=data.shape[0], k2_cap=128, s2_max=16)
+    p_esc = dataclasses.replace(p_seq, pair_chunk=4, k2_start=4)
+    seq = _sequential(data, _cols(data.shape[1]), p_seq)
     esc = build_pairwise_hist(data, _cols(data.shape[1]), p_esc)
     _assert_same_synopsis(seq, esc)
 
@@ -87,11 +72,9 @@ def test_capacity_ladder_escalation(data):
 def test_k2_capacity_guard(data):
     """At a deliberately tiny k2_cap the guard binds in both paths; the
     batched ladder is pinned at K2 and must reproduce the capped bins."""
-    p_seq = BuildParams(n_samples=data.shape[0], k2_cap=8, s2_max=16,
-                        pair_batched=False)
-    p_bat = dataclasses.replace(p_seq, pair_batched=True)
-    seq = build_pairwise_hist(data, _cols(data.shape[1]), p_seq)
-    bat = build_pairwise_hist(data, _cols(data.shape[1]), p_bat)
+    params = BuildParams(n_samples=data.shape[0], k2_cap=8, s2_max=16)
+    seq = _sequential(data, _cols(data.shape[1]), params)
+    bat = build_pairwise_hist(data, _cols(data.shape[1]), params)
     _assert_same_synopsis(seq, bat)
     for pr in bat.pairs.values():
         assert int(pr.kx) <= 8 and int(pr.ky) <= 8
@@ -105,11 +88,9 @@ def test_all_nan_pair_column():
     data = np.stack([rng.integers(0, 100, n).astype(float),
                      np.full(n, np.nan),
                      np.abs(rng.normal(50, 10, n)).round()], 1)
-    p_seq = BuildParams(n_samples=n, k2_cap=32, s2_max=16,
-                        pair_batched=False)
-    p_bat = dataclasses.replace(p_seq, pair_batched=True)
-    seq = build_pairwise_hist(data, _cols(3), p_seq)
-    bat = build_pairwise_hist(data, _cols(3), p_bat)
+    params = BuildParams(n_samples=n, k2_cap=32, s2_max=16)
+    seq = _sequential(data, _cols(3), params)
+    bat = build_pairwise_hist(data, _cols(3), params)
     _assert_same_synopsis(seq, bat)
     assert bat.columns[1].n_null == n
     assert float(bat.pairs[(0, 1)].H.sum()) == 0.0

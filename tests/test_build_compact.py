@@ -1,27 +1,26 @@
 """Convergence-compacting 2-D construction vs the sequential oracle.
 
-The compacted path (refine.refine_2d_compact driven by
+The served pair phase (refine.refine_2d_compact driven by
 build.build_pairs_compact — drain/backfill active set, shared per-column
 presorts, per-pair capacity rungs) must be *bit-for-bit* equal to the
-legacy host loop (build.build_pairs_sequential) on every workload mix:
-each pair's refinement is the same deterministic fixed-point iteration
-whatever the slot count, queue order, drain timing or occupancy_min
-re-bucketing. Covers correlated, independent, constant, NaN-heavy and
-K2-capped mixes plus drain/backfill schedule invariants (every pair
-refined exactly once, deterministic outputs, exact occupancy ledger).
+per-pair host loop (build.build_pairs_sequential, built by
+conftest._sequential through the module-private build._build_pairwise_hist)
+on every workload mix: each pair's refinement is the same deterministic
+fixed-point iteration whatever the slot count, queue order, drain timing or
+occupancy_min re-bucketing.
+Covers correlated, independent, constant, NaN-heavy and K2-capped mixes
+plus drain/backfill schedule invariants (every pair refined exactly once,
+deterministic outputs, exact occupancy ledger).
 """
 import dataclasses
 
 import numpy as np
 import pytest
 
+from conftest import _assert_same_synopsis, _cols, _sequential
 from repro.core.build import (_column_ranks, _pad_edges, _presort_pairs_host,
                               build_pairwise_hist)
-from repro.core.types import BuildParams, ColumnInfo
-
-
-def _cols(d):
-    return [ColumnInfo(name=f"c{i}", kind="int") for i in range(d)]
+from repro.core.types import BuildParams
 
 
 def _mixed_table(n=5000, seed=7):
@@ -43,18 +42,6 @@ def _independent_table(n=4000, seed=11, d=4):
                                                 n))) for i in range(d)], 1)
 
 
-def _assert_same_synopsis(a, b):
-    for h1, h2 in zip(a.hists, b.hists):
-        for f, x, y in zip(h1._fields, h1, h2):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
-                                          err_msg=f"hist field {f}")
-    assert set(a.pairs) == set(b.pairs)
-    for key in a.pairs:
-        for f, x, y in zip(a.pairs[key]._fields, a.pairs[key], b.pairs[key]):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
-                                          err_msg=f"pair {key} field {f}")
-
-
 @pytest.fixture(scope="module")
 def mixed():
     return _mixed_table()
@@ -62,14 +49,13 @@ def mixed():
 
 @pytest.fixture(scope="module")
 def seq_mixed(mixed):
-    params = BuildParams(n_samples=mixed.shape[0], k2_cap=64, s2_max=16,
-                         pair_batched=False)
-    return build_pairwise_hist(mixed, _cols(mixed.shape[1]), params)
+    params = BuildParams(n_samples=mixed.shape[0], k2_cap=64, s2_max=16)
+    return _sequential(mixed, _cols(mixed.shape[1]), params)
 
 
 def test_compact_equals_sequential_bitforbit(mixed, seq_mixed):
     params = BuildParams(n_samples=mixed.shape[0], k2_cap=64, s2_max=16,
-                         pair_batched=True, compact_drain=True, pair_chunk=4)
+                         pair_chunk=4)
     compact = build_pairwise_hist(mixed, _cols(mixed.shape[1]), params)
     assert compact.build_stats["mode"] == "compact"
     _assert_same_synopsis(seq_mixed, compact)
@@ -80,7 +66,7 @@ def test_slot_count_invariance(mixed, seq_mixed):
     bits — the schedule-independence core of the compaction claim."""
     for chunk in (1, 2, 8):
         params = BuildParams(n_samples=mixed.shape[0], k2_cap=64, s2_max=16,
-                             pair_batched=True, pair_chunk=chunk)
+                             pair_chunk=chunk)
         compact = build_pairwise_hist(mixed, _cols(mixed.shape[1]), params)
         _assert_same_synopsis(seq_mixed, compact)
 
@@ -90,7 +76,7 @@ def test_occupancy_rebucket_invariance(mixed, seq_mixed):
     pairs exactly; occupancy_min=1.0 re-buckets after every drain."""
     for occ in (0.5, 1.0):
         params = BuildParams(n_samples=mixed.shape[0], k2_cap=64, s2_max=16,
-                             pair_batched=True, pair_chunk=4,
+                             pair_chunk=4,
                              occupancy_min=occ)
         compact = build_pairwise_hist(mixed, _cols(mixed.shape[1]), params)
         _assert_same_synopsis(seq_mixed, compact)
@@ -98,22 +84,11 @@ def test_occupancy_rebucket_invariance(mixed, seq_mixed):
             assert compact.build_stats["compaction"]["relaunches"] > 0
 
 
-def test_fixed_chunk_path_still_equal(mixed, seq_mixed):
-    """compact_drain=False keeps the PR 2 fixed-chunk scheduler (benchmark
-    baseline / escape hatch) — and it must still match the oracle."""
-    params = BuildParams(n_samples=mixed.shape[0], k2_cap=64, s2_max=16,
-                         pair_batched=True, compact_drain=False, pair_chunk=4)
-    fixed = build_pairwise_hist(mixed, _cols(mixed.shape[1]), params)
-    assert fixed.build_stats["mode"] == "batched"
-    _assert_same_synopsis(seq_mixed, fixed)
-
-
 def test_independent_columns(seq_mixed):
     data = _independent_table()
-    p_seq = BuildParams(n_samples=data.shape[0], k2_cap=64, s2_max=16,
-                        pair_batched=False)
-    p_cmp = dataclasses.replace(p_seq, pair_batched=True, pair_chunk=4)
-    _assert_same_synopsis(build_pairwise_hist(data, _cols(4), p_seq),
+    p_seq = BuildParams(n_samples=data.shape[0], k2_cap=64, s2_max=16)
+    p_cmp = dataclasses.replace(p_seq, pair_chunk=4)
+    _assert_same_synopsis(_sequential(data, _cols(4), p_seq),
                           build_pairwise_hist(data, _cols(4), p_cmp))
 
 
@@ -121,10 +96,9 @@ def test_k2_capacity_guard(mixed):
     """At a tiny k2_cap the guard binds; the final rung must NOT early-drain
     capped pairs (their capped result is the real one) and must reproduce
     the sequential capped bins."""
-    p_seq = BuildParams(n_samples=mixed.shape[0], k2_cap=8, s2_max=16,
-                        pair_batched=False)
-    p_cmp = dataclasses.replace(p_seq, pair_batched=True, pair_chunk=4)
-    seq = build_pairwise_hist(mixed, _cols(mixed.shape[1]), p_seq)
+    p_seq = BuildParams(n_samples=mixed.shape[0], k2_cap=8, s2_max=16)
+    p_cmp = dataclasses.replace(p_seq, pair_chunk=4)
+    seq = _sequential(mixed, _cols(mixed.shape[1]), p_seq)
     cmp_ = build_pairwise_hist(mixed, _cols(mixed.shape[1]), p_cmp)
     _assert_same_synopsis(seq, cmp_)
     for pr in cmp_.pairs.values():
@@ -135,11 +109,9 @@ def test_capacity_ladder_escalation_per_pair(mixed):
     """A tiny first rung forces guards to bind; only the capped pairs
     re-queue one rung up (per-pair escalation) and the result still matches
     the sequential loop at full capacity."""
-    p_seq = BuildParams(n_samples=mixed.shape[0], k2_cap=128, s2_max=16,
-                        pair_batched=False)
-    p_esc = dataclasses.replace(p_seq, pair_batched=True, pair_chunk=4,
-                                k2_start=4)
-    seq = build_pairwise_hist(mixed, _cols(mixed.shape[1]), p_seq)
+    p_seq = BuildParams(n_samples=mixed.shape[0], k2_cap=128, s2_max=16)
+    p_esc = dataclasses.replace(p_seq, pair_chunk=4, k2_start=4)
+    seq = _sequential(mixed, _cols(mixed.shape[1]), p_seq)
     esc = build_pairwise_hist(mixed, _cols(mixed.shape[1]), p_esc)
     _assert_same_synopsis(seq, esc)
     comp = esc.build_stats["compaction"]
@@ -154,7 +126,7 @@ def test_schedule_ledger_and_determinism(mixed):
     exact: pair_rounds <= slot_rounds, both positive) and repeated builds
     are identical."""
     params = BuildParams(n_samples=mixed.shape[0], k2_cap=64, s2_max=16,
-                         pair_batched=True, pair_chunk=4)
+                         pair_chunk=4)
     a = build_pairwise_hist(mixed, _cols(mixed.shape[1]), params)
     b = build_pairwise_hist(mixed, _cols(mixed.shape[1]), params)
     _assert_same_synopsis(a, b)
@@ -247,10 +219,8 @@ def test_all_nan_pair_column():
     data = np.stack([rng.integers(0, 100, n).astype(float),
                      np.full(n, np.nan),
                      np.abs(rng.normal(50, 10, n)).round()], 1)
-    p_seq = BuildParams(n_samples=n, k2_cap=32, s2_max=16,
-                        pair_batched=False)
-    p_cmp = dataclasses.replace(p_seq, pair_batched=True)
-    seq = build_pairwise_hist(data, _cols(3), p_seq)
-    cmp_ = build_pairwise_hist(data, _cols(3), p_cmp)
+    params = BuildParams(n_samples=n, k2_cap=32, s2_max=16)
+    seq = _sequential(data, _cols(3), params)
+    cmp_ = build_pairwise_hist(data, _cols(3), params)
     _assert_same_synopsis(seq, cmp_)
     assert float(cmp_.pairs[(0, 1)].H.sum()) == 0.0

@@ -3,42 +3,53 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.hist2d import batched_hist2d, hist2d
-from repro.kernels.hist2d.ref import batched_hist2d_ref, hist2d_ref
+from repro.kernels.hist2d import batched_hist2d
+from repro.kernels.hist2d.ref import batched_hist2d_ref
 from repro.kernels.subbin import batched_subbin_hist
 from repro.kernels.subbin.ref import batched_subbin_hist_ref
-from repro.kernels.weightings import (batched_weightings, fused_weightings,
-                                      q_bucket)
-from repro.kernels.weightings.ref import (batched_weightings_ref,
-                                          fused_weightings_ref)
+from repro.kernels.weightings import batched_weightings, q_bucket
+from repro.kernels.weightings.ref import batched_weightings_ref
 from repro.kernels.weightings.weightings import batched_weightings_pallas
 
 
+def _np_hist2d(bi, bj, w, ki, kj):
+    """One pair's weighted 2-D histogram by ``np.add.at``, in float64."""
+    h = np.zeros((ki, kj))
+    np.add.at(h, (bi, bj), np.asarray(w, np.float64))
+    return h
+
+
 @pytest.mark.parametrize("n,ki,kj", [
-    (100, 8, 8), (1000, 37, 53), (4096, 128, 256), (2048, 300, 17),
-    (1024, 512, 512),
+    (1000, 37, 53), (4096, 128, 256), (2048, 300, 17), (1024, 512, 512),
 ])
 def test_hist2d_matches_ref(n, ki, kj):
+    """One pair (P=1) through the Pallas kernel: the counts ``np.add.at``
+    gives. (100, 8, 8) is ``test_batched_hist2d_matches_ref[1-100-8-8]``."""
     rng = np.random.default_rng(n + ki)
     bi = rng.integers(0, ki, n).astype(np.int32)
     bj = rng.integers(0, kj, n).astype(np.int32)
     w = rng.random(n).astype(np.float32)
-    out = hist2d(bi, bj, w, ki, kj)
-    ref = hist2d_ref(jnp.asarray(bi), jnp.asarray(bj), jnp.asarray(w), ki, kj)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
+    out = np.asarray(batched_hist2d(bi[None], bj[None], w[None], ki, kj,
+                                    use_pallas=True))
+    assert out.shape == (1, ki, kj)
+    np.testing.assert_allclose(out[0], _np_hist2d(bi, bj, w, ki, kj),
+                               rtol=1e-6)
 
 
 @pytest.mark.parametrize("wdtype", [np.float32, np.float64, np.int32])
 def test_hist2d_weight_dtypes(wdtype):
+    """One pair (P=1) through the Pallas kernel, for each weight dtype:
+    the counts ``np.add.at`` gives."""
     rng = np.random.default_rng(0)
     n, ki, kj = 500, 16, 16
     bi = rng.integers(0, ki, n).astype(np.int32)
     bj = rng.integers(0, kj, n).astype(np.int32)
     w = rng.integers(0, 3, n).astype(wdtype)
-    out = hist2d(bi, bj, w, ki, kj)
-    ref = hist2d_ref(jnp.asarray(bi), jnp.asarray(bj),
-                     jnp.asarray(w, jnp.float32), ki, kj)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
+    out = np.asarray(batched_hist2d(bi[None], bj[None], w[None], ki, kj,
+                                    use_pallas=True))
+    assert out.shape == (1, ki, kj)
+    np.testing.assert_allclose(out[0], _np_hist2d(bi, bj, w, ki, kj),
+                               rtol=1e-6)
     assert float(out.sum()) == pytest.approx(float(w.sum()))
 
 
@@ -46,7 +57,7 @@ def test_hist2d_weight_dtypes(wdtype):
     (1, 100, 8, 8), (3, 500, 37, 53), (2, 2048, 128, 256), (4, 1000, 300, 17),
 ])
 def test_batched_hist2d_matches_ref(p, n, ki, kj):
-    """Pair-batched Pallas kernel == oracle == per-pair single kernel."""
+    """Pair-batched Pallas kernel == jnp oracle == ``np.add.at`` per pair."""
     rng = np.random.default_rng(p * n + ki)
     bi = rng.integers(0, ki, (p, n)).astype(np.int32)
     bj = rng.integers(0, kj, (p, n)).astype(np.int32)
@@ -57,9 +68,8 @@ def test_batched_hist2d_matches_ref(p, n, ki, kj):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     for pi in range(p):
-        single = hist2d_ref(jnp.asarray(bi[pi]), jnp.asarray(bj[pi]),
-                            jnp.asarray(w[pi]), ki, kj)
-        np.testing.assert_allclose(np.asarray(out)[pi], np.asarray(single),
+        np.testing.assert_allclose(np.asarray(out)[pi],
+                                   _np_hist2d(bi[pi], bj[pi], w[pi], ki, kj),
                                    rtol=1e-5, atol=1e-5)
 
 
@@ -156,23 +166,13 @@ def test_subbin_counts_matches_inline_scatter():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.parametrize("el,k2,k1", [
-    (1, 16, 16), (3, 64, 80), (5, 200, 260), (2, 128, 128), (4, 384, 400),
-])
-def test_fused_weightings_matches_ref(el, k2, k1):
-    rng = np.random.default_rng(el * k2)
-    H = (rng.random((el, k2, k2)) * 10).astype(np.float32)
-    beta = rng.random((el, k2)).astype(np.float32)
-    hx = H.sum(2) + 1.0
-    fold = np.zeros((el, k1, k2), np.float32)
-    idx = np.sort(rng.integers(0, k2, k1))   # 1-D bin -> containing row
-    for li in range(el):
-        fold[li, np.arange(k1), idx] = 1
-    out = fused_weightings(H, beta, fold, hx)
-    ref = fused_weightings_ref(jnp.asarray(H), jnp.asarray(beta),
-                               jnp.asarray(fold), jnp.asarray(hx))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
+def _per_query_oracle(h_stack, beta, fold, hx):
+    """One query's weightings, prod_l fold_l(clip(H_l beta_l / hx_l, 0, 1))
+    (Eq. 25/27/28), as a float32 einsum: beta (L, K2) -> (K1,)."""
+    v = jnp.einsum("lab,lb->la", h_stack, beta)          # (L, K2)
+    p_row = jnp.clip(v / jnp.maximum(hx, 1e-30), 0.0, 1.0)
+    p1 = jnp.einsum("lka,la->lk", fold, p_row)           # (L, K1)
+    return np.asarray(jnp.prod(p1, axis=0))
 
 
 def _padded_device(x, shape):
@@ -206,6 +206,10 @@ def _parent_launch(H, beta, fold, hx):
     pytest.param(5, 3, 70, 90, "host", id="5-3-70-90"),
     pytest.param(17, 2, 200, 260, "host", id="17-2-200-260"),
     pytest.param(64, 4, 128, 128, "host", id="64-4-128-128"),
+    (1, 3, 64, 80, "host"),
+    (1, 5, 200, 260, "host"),
+    (1, 2, 128, 128, "host"),
+    (1, 4, 384, 400, "host"),
     (1, 1, 16, 16, "device"),
     (3, 2, 100, 130, "device"),
     (9, 3, 70, 90, "host"),
@@ -216,8 +220,9 @@ def _parent_launch(H, beta, fold, hx):
 ])
 def test_batched_weightings_matches_per_query(q, el, k2, k1, stacks):
     """Query-batched kernel == per-query oracle, row by row, for both the
-    Pallas path and the jitted-jnp path; the Pallas launch returns a host
-    array bit-identical to the kernel's device-sliced result."""
+    Pallas path and the jitted-jnp path, a single query (Q=1) included;
+    the Pallas launch returns a host array bit-identical to the kernel's
+    device-sliced result."""
     rng = np.random.default_rng(q * k2 + el)
     H = (rng.random((el, k2, k2)) * 10).astype(np.float32)
     hx = H.sum(2) + 1.0
@@ -226,9 +231,9 @@ def test_batched_weightings_matches_per_query(q, el, k2, k1, stacks):
     for li in range(el):
         fold[li, np.arange(k1), idx] = 1
     beta = rng.random((q, el, k2)).astype(np.float32)
-    seq = np.stack([np.asarray(fused_weightings_ref(
+    seq = np.stack([_per_query_oracle(
         jnp.asarray(H), jnp.asarray(beta[qi]), jnp.asarray(fold),
-        jnp.asarray(hx))) for qi in range(q)])
+        jnp.asarray(hx)) for qi in range(q)])
     args = (H, beta, fold, hx)
     if stacks == "device":
         k2p, k1p = -(-k2 // 128) * 128, -(-k1 // 128) * 128
@@ -339,7 +344,7 @@ def test_fastpath_batch_launches_once(synopsis, monkeypatch):
 
 
 def test_batched_weightings_ref_reduces_to_single():
-    """Q=1 batched ref == single-query ref exactly (same einsum graph)."""
+    """Q=1 batched ref == the per-query oracle (the same einsum graph)."""
     rng = np.random.default_rng(11)
     el, k2, k1 = 2, 32, 40
     H = rng.random((el, k2, k2)).astype(np.float32)
@@ -349,30 +354,45 @@ def test_batched_weightings_ref_reduces_to_single():
     beta = rng.random((1, el, k2)).astype(np.float32)
     one = batched_weightings_ref(jnp.asarray(H), jnp.asarray(beta),
                                  jnp.asarray(fold), jnp.asarray(hx))
-    single = fused_weightings_ref(jnp.asarray(H), jnp.asarray(beta[0]),
-                                  jnp.asarray(fold), jnp.asarray(hx))
-    np.testing.assert_allclose(np.asarray(one[0]), np.asarray(single),
+    single = _per_query_oracle(jnp.asarray(H), jnp.asarray(beta[0]),
+                               jnp.asarray(fold), jnp.asarray(hx))
+    np.testing.assert_allclose(np.asarray(one[0]), single,
                                rtol=1e-6, atol=1e-7)
 
 
-def test_fused_weightings_identity_predicate():
-    """A beta of all-ones gives probability 1 in every bin."""
+def test_batched_weightings_identity_predicate():
+    """A beta of all-ones gives probability 1 in every bin (one query on
+    the Pallas path)."""
     rng = np.random.default_rng(7)
     k2, k1 = 32, 32
     H = rng.integers(0, 5, (1, k2, k2)).astype(np.float32)
     hx = H.sum(2)
     fold = np.zeros((1, k1, k2), np.float32)
     fold[0, np.arange(k1), np.arange(k2)] = 1
-    beta = np.ones((1, k2), np.float32)
-    out = np.asarray(fused_weightings(H, beta, fold, hx))
+    beta = np.ones((1, 1, k2), np.float32)
+    out = batched_weightings(H, beta, fold, hx, use_pallas=True)[0]
     mask = hx[0] > 0
     np.testing.assert_allclose(out[mask], 1.0, rtol=1e-6)
 
 
+def _pair_betas(ph, agg_col, pair_leaves, k2max):
+    """(3, L, K2max) coverage matrix for one query's pair leaves, one leaf
+    at a time: the oracle of ``FastPath._pair_betas_batch``."""
+    from repro.core.fastpath import _slice_beta
+    betas = np.zeros((3, len(pair_leaves), k2max), np.float32)
+    for li, leaf in enumerate(pair_leaves):
+        pr = ph.pair(agg_col, leaf.col)
+        triple = _slice_beta(ph, leaf, pr.hy, pr.uy, pr.vminy, pr.vmaxy,
+                             ph.columns[leaf.col].mu)
+        for idx in range(3):
+            betas[idx, li, :len(triple[idx])] = triple[idx]
+    return betas
+
+
 def test_pair_betas_batch_bit_for_bit(synopsis):
     """Vectorized per-leaf beta assembly (_pair_betas_batch) is bit-for-bit
-    equal to stacking the per-query _pair_betas calls, across operators,
-    out-of-range literals and consolidated interval leaves."""
+    equal to stacking the per-query ``_pair_betas`` oracle, across
+    operators, out-of-range literals and consolidated interval leaves."""
     from repro.core import weightings as wlib
     from repro.core.fastpath import FastPath
     fp = FastPath(use_pallas=False)
@@ -391,7 +411,7 @@ def test_pair_betas_batch_bit_for_bit(synopsis):
         leaf_lists.append(leaves)
     k2max = 512
     batched = fp._pair_betas_batch(synopsis, agg, leaf_lists, k2max)
-    seq = np.stack([fp._pair_betas(synopsis, agg, pls, k2max)
+    seq = np.stack([_pair_betas(synopsis, agg, pls, k2max)
                     for pls in leaf_lists])
     np.testing.assert_array_equal(batched, seq)
 
@@ -423,7 +443,7 @@ def test_pair_betas_batch_group_by_bit_for_bit(synopsis, shape, computed):
     leaf_lists = [_brushes(qi, shape) for qi in range(14)]
     memo = _SliceMemo()
     batched = fp._pair_betas_batch(synopsis, 0, leaf_lists, 512, memo)
-    seq = np.stack([fp._pair_betas(synopsis, 0, pls, 512)
+    seq = np.stack([_pair_betas(synopsis, 0, pls, 512)
                     for pls in leaf_lists])
     np.testing.assert_array_equal(batched, seq)
     assert memo.computed == computed
@@ -449,7 +469,8 @@ def _group_trees(same_col: bool, vary: bool = False):
 def test_fastpath_batch_memo_is_bit_for_bit(synopsis):
     """``FastPath.batch`` with same-column brushes repeated across the
     plans (two distinct ones) gives the triples of the path without the
-    memo: per-plan ``_split_leaves`` and stacked per-plan ``_pair_betas``."""
+    memo: per-plan ``_split_leaves`` and the stacked per-plan
+    ``_pair_betas`` oracle."""
     from repro.core.fastpath import FastPath
     trees = _group_trees(same_col=True, vary=True)
     fp = FastPath(use_pallas=False)
@@ -460,8 +481,7 @@ def test_fastpath_batch_memo_is_bit_for_bit(synopsis):
     plain._split_leaves = (lambda ph, agg, tree, memo=None:
                            FastPath._split_leaves(plain, ph, agg, tree))
     plain._pair_betas_batch = (lambda ph, agg, lls, k2max, memo=None:
-                               np.stack([plain._pair_betas(ph, agg, pls,
-                                                           k2max)
+                               np.stack([_pair_betas(ph, agg, pls, k2max)
                                          for pls in lls]))
     want = plain.batch(synopsis, 0, trees, corrected=False)
     assert len(got) == len(want) == 14
@@ -491,7 +511,9 @@ def test_fastpath_batch_counts_slices(synopsis, same_col):
 
 def test_fastpath_batch_equals_single(synopsis):
     """FastPath.batch (one fused launch + vectorized betas) matches the
-    per-query FastPath.__call__ triples."""
+    NumPy reference weightings (``repro.core.weightings``) of each query:
+    the launch contracts in float32 against the reference's float64."""
+    from repro.core import weightings as wlib
     from repro.core.fastpath import FastPath
     from repro.core.query import QueryEngine
     fp = FastPath(use_pallas=False)
@@ -502,22 +524,32 @@ def test_fastpath_batch_equals_single(synopsis):
     batch = fp.batch(synopsis, 0, trees, corrected=False)
     assert batch is not None
     for tree, triple in zip(trees, batch):
-        single = fp(synopsis, 0, tree, corrected=False)
+        single = wlib.weightings(synopsis, 0, tree)
         for got, want in zip(triple, single):
             np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
 
 
-def test_fastpath_equals_reference_engine(synopsis):
-    from repro.core.fastpath import make_fastpath
+@pytest.mark.parametrize("sql", [
+    "SELECT COUNT(c0) FROM t WHERE c1 > 300 AND c2 < 900",
+    "SELECT AVG(c2) FROM t WHERE c1 >= 250 AND c1 < 350",
+    "SELECT SUM(c1) FROM t WHERE c2 <= 900 AND c0 < 500",
+    "SELECT MIN(c1) FROM t WHERE c1 > 100",
+    # OR: no fused launch; the engine answers on the reference path
+    "SELECT AVG(c1) FROM t WHERE c0 < 100 OR c3 = 2",
+])
+def test_fastpath_equals_reference_engine(synopsis, sql):
+    """An answer executed with ``FastPath.batch``'s weightings (the Pallas
+    path) equals the engine's own NumPy reference answer."""
+    from repro.core.fastpath import FastPath
     from repro.core.query import QueryEngine
-    e_ref = QueryEngine(synopsis)
-    e_fast = QueryEngine(synopsis, fastpath=make_fastpath(use_pallas=True))
-    for sql in ("SELECT COUNT(c0) FROM t WHERE c1 > 300 AND c2 < 900",
-                "SELECT AVG(c2) FROM t WHERE c1 >= 250 AND c1 < 350",
-                "SELECT SUM(c1) FROM t WHERE c2 <= 900 AND c0 < 500",
-                "SELECT MIN(c1) FROM t WHERE c1 > 100",
-                # OR falls back to the reference path inside the engine
-                "SELECT AVG(c1) FROM t WHERE c0 < 100 OR c3 = 2"):
-        r1, r2 = e_ref.query(sql), e_fast.query(sql)
-        np.testing.assert_allclose(r1.as_tuple(), r2.as_tuple(),
-                                   rtol=1e-5, atol=1e-6)
+    eng = QueryEngine(synopsis)
+    plan = eng.plan_sql(sql)
+    triples = FastPath(use_pallas=True).batch(synopsis, plan.exec_col,
+                                              [plan.tree], eng.corrected)
+    if " OR " in sql:
+        assert triples is None
+        return
+    r1 = eng.execute_plan(plan)
+    r2 = eng.execute_plan(plan, weightings=triples[0])
+    np.testing.assert_allclose(r1.as_tuple(), r2.as_tuple(),
+                               rtol=1e-5, atol=1e-6)
