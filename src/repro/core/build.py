@@ -185,7 +185,8 @@ def _presort_pairs_host(x, y, valid, rx=None, ry=None):
     """Host-side ``refine.presort_pairs`` (numpy's sort beats XLA:CPU's).
 
     Same layout and same (stable lexsort) semantics; done once per chunk —
-    the per-round unique counts then need no sort at all.
+    the per-round unique counts then need no sort at all, and the per-round
+    bins no per-point search.
 
     With ``rx``/``ry`` (per-pair rows of the shared ``_column_ranks``
     table) the two-key float lexsorts become single stable argsorts of the
@@ -198,11 +199,13 @@ def _presort_pairs_host(x, y, valid, rx=None, ry=None):
     """
     n_pairs, n = x.shape
     xo1 = np.empty_like(x)
-    yo1 = np.empty_like(y)
     vo1 = np.empty_like(valid)
-    xo2 = np.empty_like(x)
     yo2 = np.empty_like(y)
     vo2 = np.empty_like(valid)
+    perm12 = np.empty((n_pairs, n), np.int32)
+    perm21 = np.empty((n_pairs, n), np.int32)
+    inv = np.empty(n, np.int32)
+    rows = np.arange(n, dtype=np.int32)
     big = np.int64(n + 1) * np.int64(n + 1)
     for p in range(n_pairs):
         if rx is None:
@@ -215,15 +218,19 @@ def _presort_pairs_host(x, y, valid, rx=None, ry=None):
             key2 = np.where(valid[p], ry[p] * np.int64(n + 1) + rx[p], big)
             o1 = np.argsort(key1, kind="stable")
             o2 = np.argsort(key2, kind="stable")
-        xo1[p], yo1[p], vo1[p] = x[p][o1], y[p][o1], valid[p][o1]
-        xo2[p], yo2[p], vo2[p] = x[p][o2], y[p][o2], valid[p][o2]
+        xo1[p], vo1[p] = x[p][o1], valid[p][o1]
+        yo2[p], vo2[p] = y[p][o2], valid[p][o2]
+        inv[o2] = rows
+        perm12[p] = inv[o1]
+        inv[o1] = rows
+        perm21[p] = inv[o2]
     new1 = np.empty((n_pairs, n), bool)
     new1[:, 0] = True
     new1[:, 1:] = xo1[:, 1:] != xo1[:, :-1]
     new2 = np.empty((n_pairs, n), bool)
     new2[:, 0] = True
     new2[:, 1:] = yo2[:, 1:] != yo2[:, :-1]
-    return xo1, yo1, vo1, new1, xo2, yo2, vo2, new2
+    return xo1, vo1, new1, yo2, vo2, new2, perm12, perm21
 
 
 def _pow2_floor(n: int) -> int:
@@ -435,7 +442,8 @@ def build_pairs_compact(sample: np.ndarray, hists: list, params,
         for cap, gids in sorted(by_cap.items()):
             size = _pow2_ceil(len(gids))
             idx = gids + [gids[0]] * (size - len(gids))
-            data = tuple(jnp.asarray(arr[idx]) for arr in pres)
+            # All but perm21: metadata bins x only in (x, y) order.
+            data = tuple(jnp.asarray(arr[idx]) for arr in pres[:-1])
             ex_m = np.full((size, cap + 1), np.inf, np.float64)
             ey_m = np.full((size, cap + 1), np.inf, np.float64)
             ex_m[:, :2] = 0.0

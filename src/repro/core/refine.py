@@ -33,7 +33,11 @@ Each pair is presorted once by (x, y) and (y, x) (``presort_pairs``), which
 turns the per-round ``lexsort`` in ``_slice_unique`` into cheap
 run-boundary flag sums — counts are exact integers, so the compacting
 scheduler is bit-for-bit equal to the per-pair ``refine_2d`` loop
-(asserted in tests). ``refine_2d``/``pair_metadata`` remain as the
+(asserted in tests). The presort also bins the points: a round uses no
+per-point ``searchsorted``. It searches each pair's K+1 edges into the
+sorted dimension of each order and takes a running count over the rows
+(``_presort_bins``); the other dimension's bins are those, permuted
+between the two orders. ``refine_2d``/``pair_metadata`` remain as the
 single-pair reference path, the oracle ``build.build_pairs_sequential``
 runs.
 """
@@ -387,10 +391,13 @@ def presort_pairs(x, y, valid):
 
     x/y/valid: (P, N). Invalid rows sort to the tail (+inf keys). Returns
     the points of every pair in (x, y) order and in (y, x) order plus
-    run-start flags:
+    run-start flags and the permutations between the two orders:
 
-      xo1/yo1/vo1/new1: values, validity and x-run starts in (x, y) order;
-      xo2/yo2/vo2/new2: values, validity and y-run starts in (y, x) order.
+      xo1/vo1/new1: x values, validity and x-run starts in (x, y) order;
+      yo2/vo2/new2: y values, validity and y-run starts in (y, x) order;
+      perm12/perm21: (i32) where each row of one order sits in the other,
+                     so y in (x, y) order is ``yo2[perm12]`` and x in
+                     (y, x) order is ``xo1[perm21]``.
 
     Within an x-run (equal x => equal x-bin in any grid), points are sorted
     by y, so equal y-bins are contiguous — a point starts a new (x-value,
@@ -413,19 +420,38 @@ def presort_pairs(x, y, valid):
     def take(a, o):
         return jnp.take_along_axis(a, o, axis=1)
 
-    xo1, yo1, vo1 = take(x, o1), take(y, o1), take(valid, o1)
-    xo2, yo2, vo2 = take(x, o2), take(y, o2), take(valid, o2)
+    xo1, vo1 = take(x, o1), take(valid, o1)
+    yo2, vo2 = take(y, o2), take(valid, o2)
+    # perm12 = inverse(o2)[o1], perm21 = inverse(o1)[o2].
+    inv1 = jnp.argsort(o1, axis=1).astype(jnp.int32)
+    inv2 = jnp.argsort(o2, axis=1).astype(jnp.int32)
     first = jnp.ones((x.shape[0], 1), bool)
     new1 = jnp.concatenate([first, xo1[:, 1:] != xo1[:, :-1]], axis=1)
     new2 = jnp.concatenate([first, yo2[:, 1:] != yo2[:, :-1]], axis=1)
-    return xo1, yo1, vo1, new1, xo2, yo2, vo2, new2
+    return (xo1, vo1, new1, yo2, vo2, new2, take(inv2, o1), take(inv1, o2))
 
 
-def _bin_index_b(vals, edges, k):
-    """(P, N) values x (P, K+1) edges -> per-point bin indices, per pair."""
-    idx = jax.vmap(
-        lambda v, e: jnp.searchsorted(e, v, side="right"))(vals, edges) - 1
-    return jnp.clip(idx, 0, jnp.maximum(k[:, None] - 1, 0))
+def _presort_bins(vals_sorted, valid_sorted, edges, k):
+    """Per-point bin indices of presorted rows, with no per-point search.
+
+    vals_sorted/valid_sorted: (P, N), non-decreasing over each row's valid
+    prefix (invalid rows last); edges: (P, K+1) sorted, +inf padded.
+    Searches the K+1 edges into the row, keyed +inf past its valid prefix:
+    ``pos[j]`` is the first row n with ``key[n] >= edges[j]``. As the key is
+    non-decreasing, ``edges[j] <= key[n]`` iff ``pos[j] <= n``, so the count
+    of such j, a running sum of the ``pos`` hits, less one is exactly
+    ``searchsorted(edges, v, "right") - 1`` on every valid row (invalid rows
+    get some in-range bin; callers weight them 0). Clipped to ``[0, k-1]``
+    as ``_bin_index`` is. Returns ``(bins, pos)``: (P, N) i32, (P, K+1).
+    """
+    p, n = vals_sorted.shape
+    keyed = jnp.where(valid_sorted, vals_sorted, _INF)
+    pos = jax.vmap(lambda kv, e: jnp.searchsorted(
+        kv, e, side="left"))(keyed, edges)                     # (P, K+1)
+    hits = jnp.zeros((p, n), jnp.int32).at[
+        jnp.arange(p)[:, None], pos].add(1, mode="drop")
+    idx = jnp.cumsum(hits, axis=1) - 1
+    return jnp.clip(idx, 0, jnp.maximum(k[:, None] - 1, 0)), pos
 
 
 def _unique_flags(new_run, other_bin, valid):
@@ -446,7 +472,7 @@ def _chi2_from_hbar_b(hbar, h_cell, s, s_max: int, crit_table):
     return stat, crit
 
 
-def _round_2d_batch(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
+def _round_2d_batch(xo1, vo1, new1, yo2, vo2, new2, perm12, perm21,
                     ex, ey, kx, ky, min_points, crit_table, *,
                     k2: int, s_max: int, use_pallas: bool,
                     interpret: bool | None):
@@ -459,14 +485,16 @@ def _round_2d_batch(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
     with per-pair split and guard-bound flags for this round. Exactly the
     ops of the legacy per-pair ``refine_2d`` body on each pair's lane, so
     any scheduler built on it stays bit-for-bit equal to the sequential
-    path.
+    path. Each point's bins come from the presort (``_presort_bins`` on
+    the sorted dimension of each order, ``perm12``/``perm21`` for the
+    other), not from a per-point search.
     """
     p = xo1.shape[0]
     ncell = k2 * k2
-    bio1 = _bin_index_b(xo1, ex, kx)
-    bjo1 = _bin_index_b(yo1, ey, ky)
-    bio2 = _bin_index_b(xo2, ex, kx)
-    bjo2 = _bin_index_b(yo2, ey, ky)
+    bio1, _ = _presort_bins(xo1, vo1, ex, kx)
+    bjo2, _ = _presort_bins(yo2, vo2, ey, ky)
+    bjo1 = jnp.take_along_axis(bjo2, perm12, axis=1)
+    bio2 = jnp.take_along_axis(bio1, perm21, axis=1)
     cell1 = bio1 * k2 + bjo1
     cell2 = bio2 * k2 + bjo2
 
@@ -531,7 +559,7 @@ def _round_2d_batch(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
 @functools.partial(jax.jit, static_argnames=("n_slots", "k2", "s_max",
                                              "max_rounds", "drain_capped",
                                              "use_pallas", "interpret"))
-def refine_2d_compact(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
+def refine_2d_compact(xo1, vo1, new1, yo2, vo2, new2, perm12, perm21,
                       ex0, ey0, kx0, ky0, rounds0, capped0, n_pending,
                       min_points, crit_table, occupancy_min, *,
                       n_slots: int, k2: int, s_max: int = 32,
@@ -638,8 +666,9 @@ def refine_2d_compact(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
          out_ex, out_ey, out_kx, out_ky, out_capped, out_rounds,
          out_done, occ_hist, loop_rounds, active_rounds) = st
         nex, ney, nkx, nky, n_split, cap_r = _round_2d_batch(
-            xo1[slot_pair], yo1[slot_pair], vo1[slot_pair], new1[slot_pair],
-            xo2[slot_pair], yo2[slot_pair], vo2[slot_pair], new2[slot_pair],
+            xo1[slot_pair], vo1[slot_pair], new1[slot_pair],
+            yo2[slot_pair], vo2[slot_pair], new2[slot_pair],
+            perm12[slot_pair], perm21[slot_pair],
             sex, sey, skx, sky, min_points, crit_table, k2=k2, s_max=s_max,
             use_pallas=use_pallas, interpret=interpret)
         am = active
@@ -693,7 +722,7 @@ def refine_2d_compact(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
 
 
 @functools.partial(jax.jit, static_argnames=("k2", "use_pallas", "interpret"))
-def pair_metadata_batch(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
+def pair_metadata_batch(xo1, vo1, new1, yo2, vo2, new2, perm12,
                         ex, ey, kx, ky, *, k2: int,
                         use_pallas: bool = False,
                         interpret: bool | None = None):
@@ -704,11 +733,14 @@ def pair_metadata_batch(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
     row's points are a contiguous slice of the (x, y)-sorted array (bin
     index depends on x alone), so row extrema are the slice ends and
     distinct counts are prefix-sum differences of the run flags — exactly
-    the values the legacy segment ops produce.
+    the values the legacy segment ops produce. The per-point bins and the
+    slice bounds come from one edge search per dimension
+    (``_presort_bins``).
     """
     p, n = xo1.shape
-    bio1 = _bin_index_b(xo1, ex, kx)
-    bjo1 = _bin_index_b(yo1, ey, ky)
+    bio1, posx = _presort_bins(xo1, vo1, ex, kx)
+    bjo2, posy = _presort_bins(yo2, vo2, ey, ky)
+    bjo1 = jnp.take_along_axis(bjo2, perm12, axis=1)
     ones1 = jnp.where(vo1, 1.0, 0.0)
     H = batched_hist2d(bio1, bjo1, ones1, k2, k2, use_pallas=use_pallas,
                        interpret=interpret)                    # (P, K2, K2)
@@ -716,10 +748,7 @@ def pair_metadata_batch(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
     hy = H.sum(axis=1)
     nv = jnp.sum(vo1, axis=1)                                  # (P,)
 
-    def slice_meta(vals_sorted, valid_sorted, run_flags, edges, k):
-        keyed = jnp.where(valid_sorted, vals_sorted, _INF)
-        pos = jax.vmap(lambda kv, e: jnp.searchsorted(
-            kv, e, side="left"))(keyed, edges)                 # (P, K2+1)
+    def slice_meta(pos, vals_sorted, valid_sorted, run_flags, k):
         t = jnp.arange(k2)[None, :]
         lo = pos[:, :-1]
         # Half-open bins except the last valid one (closed): its slice runs
@@ -737,8 +766,8 @@ def pair_metadata_batch(xo1, yo1, vo1, new1, xo2, yo2, vo2, new2,
                                    jnp.clip(hi - 1, 0, n - 1), axis=1)
         return uu, vmin, vmax
 
-    ux, vminx, vmaxx = slice_meta(xo1, vo1, new1, ex, kx)
-    uy, vminy, vmaxy = slice_meta(yo2, vo2, new2, ey, ky)
+    ux, vminx, vmaxx = slice_meta(posx, xo1, vo1, new1, kx)
+    uy, vminy, vmaxy = slice_meta(posy, yo2, vo2, new2, ky)
 
     empty_x = hx == 0
     vminx = jnp.where(empty_x, ex[:, :-1], vminx)

@@ -121,7 +121,7 @@ def test_device_presort_matches_host_presort():
     host = _presort_pairs_host(x, y, valid)
     import jax.numpy as jnp
     dev = presort_pairs(jnp.asarray(x), jnp.asarray(y), jnp.asarray(valid))
-    for name, h, d in zip("xo1 yo1 vo1 new1 xo2 yo2 vo2 new2".split(),
+    for name, h, d in zip("xo1 vo1 new1 yo2 vo2 new2 perm12 perm21".split(),
                           host, dev):
         np.testing.assert_array_equal(h, np.asarray(d), err_msg=name)
 

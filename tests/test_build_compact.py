@@ -152,9 +152,78 @@ def test_rank_presort_matches_lexsort_presort():
     rk = _presort_pairs_host(x[:2], y[:2], valid[:2],
                              np.stack([ranks[0], ranks[2]]),
                              np.stack([ranks[1], ranks[3]]))
-    for name, h, r in zip("xo1 yo1 vo1 new1 xo2 yo2 vo2 new2".split(),
+    for name, h, r in zip("xo1 vo1 new1 yo2 vo2 new2 perm12 perm21".split(),
                           lex, rk):
         np.testing.assert_array_equal(h, r, err_msg=name)
+
+
+@pytest.mark.parametrize("branch", ["lexsort", "rank"])
+def test_presort_permutations_map_the_two_orders(branch):
+    """``perm12``/``perm21`` carry each row between the two sort orders:
+    y in (x, y) order is ``yo2[perm12]`` and x in (y, x) order is
+    ``xo1[perm21]``, validity likewise, on both presort branches."""
+    rng = np.random.default_rng(4)
+    p, n = 3, 400
+    x = rng.integers(0, 25, (p, n)).astype(float)    # many ties
+    y = rng.integers(0, 25, (p, n)).astype(float)
+    valid = rng.random((p, n)) < 0.85
+    ranks = None
+    if branch == "rank":
+        r = _column_ranks(np.concatenate([x, y]).T)
+        ranks = (r[:p], r[p:])
+    xo1, vo1, _, yo2, vo2, _, perm12, perm21 = _presort_pairs_host(
+        x, y, valid, *(ranks or ()))
+    assert perm12.dtype == np.int32 and perm21.dtype == np.int32
+    for q in range(p):
+        kx = np.where(valid[q], x[q], np.inf)
+        ky = np.where(valid[q], y[q], np.inf)
+        o1, o2 = np.lexsort((ky, kx)), np.lexsort((kx, ky))
+        np.testing.assert_array_equal(yo2[q][perm12[q]], y[q][o1])
+        np.testing.assert_array_equal(vo2[q][perm12[q]], valid[q][o1])
+        np.testing.assert_array_equal(xo1[q][perm21[q]], x[q][o2])
+        np.testing.assert_array_equal(vo1[q][perm21[q]], valid[q][o2])
+        np.testing.assert_array_equal(perm21[q][perm12[q]], np.arange(n))
+
+
+def _nested_while_shapes(hlo: str) -> tuple[list, list]:
+    """Result shapes of the ``while`` ops in optimised HLO text: those of
+    the entry computation and those nested in any other computation."""
+    entry, nested, in_entry = [], [], False
+    for line in hlo.splitlines():
+        if line and not line[0].isspace():
+            in_entry = line.startswith("ENTRY")
+        elif " while(" in line:
+            shape = line.split(" = ", 1)[1].split(" while(", 1)[0]
+            (entry if in_entry else nested).append(shape)
+    return entry, nested
+
+
+def test_compact_round_has_no_per_point_search():
+    """No loop nested in ``refine_2d_compact``'s optimised HLO carries an
+    integer (S, N) operand: the bounds of a per-point binary search. The
+    searches of the K+1 edges into each sorted row may stay loops; their
+    bounds are (S, K+1)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import refine
+
+    P, S, N, K = 8, 4, 200, 16       # N equals no other dimension
+    sd = jax.ShapeDtypeStruct
+    f64, i32, b = jnp.float64, jnp.int32, jnp.bool_
+    rows = [sd((P, N), t) for t in (f64, b, b, f64, b, b, i32, i32)]
+    args = rows + [sd((P, K + 1), f64), sd((P, K + 1), f64),
+                   sd((P,), i32), sd((P,), i32), sd((P,), i32),
+                   sd((P,), b), sd((), i32), sd((), f64), sd((17,), f64),
+                   sd((), f64)]
+    hlo = refine.refine_2d_compact.lower(
+        *args, n_slots=S, k2=K, s_max=16).compile().as_text()
+    entry, nested = _nested_while_shapes(hlo)
+    assert len(entry) == 1               # the drain/backfill loop
+    per_point = re.compile(rf"\b[su]\d+\[\d+,{N}\]")
+    assert not [s for s in nested if per_point.search(s)], nested
 
 
 def test_refine_2d_compact_direct_invariants():
